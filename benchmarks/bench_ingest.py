@@ -16,13 +16,12 @@ Sections:
                 ≥3×@K=16 acceptance numbers.
 * one-kernel:   the PR-7 single-Pallas-call ingest vs the fused-jnp path,
                 fold-level and end-to-end. Rows are labelled by execution
-                mode: ``interpret`` (mandatory; what this CPU container
-                can run — the Pallas emulator still traces to XLA under
-                jit, so these are real CPU numbers, just not the TPU
-                claim) and ``compiled`` (the lane the kernel exists for;
-                requires a TPU backend + ``REPRO_PALLAS_COMPILE=1``,
-                recorded as unavailable-with-reason otherwise — never
-                fabricated).
+                mode, which the platform decides
+                (``kernels/ops.interpret_mode``): ``interpret`` on a CPU
+                backend (the Pallas emulator still traces to XLA under
+                jit, so these are real CPU numbers, not a TPU claim) and
+                ``compiled`` elsewhere. The lane that did not run is
+                recorded as unavailable-with-reason — never fabricated.
 * executor:     end-to-end items/s + emission step-latency p50/p99 for
                 both modes (batched / pipelined), sharded and not, on the
                 fused path with donated state buffers.
@@ -108,19 +107,6 @@ def _assert_answers_identical(k: int, other: str, key) -> bool:
     return True
 
 
-def _compiled_lane():
-    """(available, reason) for compiled-kernel rows. Both gates must
-    hold; the reason string lands in the JSON so a reader knows why the
-    compiled numbers are absent instead of suspecting they were elided."""
-    backend = jax.default_backend()
-    if backend != "tpu":
-        return False, (f"jax backend is {backend!r}; compiled Pallas "
-                       "lowering needs a TPU")
-    if not kops.pallas_compile_enabled():
-        return False, "set REPRO_PALLAS_COMPILE=1 to lower the kernel"
-    return True, ""
-
-
 def _executor_stats(mode_cls, cfg, chunks, key):
     """items/s + emission-latency percentiles for one executor run
     (warm pass first so trace+compile stays out of the timed region)."""
@@ -181,25 +167,24 @@ def _validate_report(report: dict) -> None:
         for f in ("chunk_size", "fused_us", "masked_us", "speedup"):
             num(row, f, f"chunk_sweep_k8[{i}]")
     ok = report["onekernel"]
-    interp_rows = {n: r for n, r in ok.get("interpret", {}).items()
-                   if isinstance(r, dict)}
-    _require(len(interp_rows) > 0, "onekernel.interpret",
-             "no rows (interpret-mode numbers are mandatory)")
-    for name, row in interp_rows.items():
-        for f in ("chunk_size", "onekernel_us", "fused_us",
-                  "speedup_vs_fused"):
-            num(row, f, f"onekernel.interpret.{name}")
-    comp = ok.get("compiled", {})
-    if comp.get("available") is False:
-        _require(isinstance(comp.get("reason"), str) and comp["reason"],
-                 "onekernel.compiled.reason",
-                 "unavailable lane must say why")
-    else:
-        _require(len(comp) > 0, "onekernel.compiled",
+    ran = 0
+    for lane in ("interpret", "compiled"):
+        rows = ok.get(lane, {})
+        if rows.get("available") is False:
+            _require(isinstance(rows.get("reason"), str) and rows["reason"],
+                     f"onekernel.{lane}.reason",
+                     "unavailable lane must say why")
+            continue
+        ran += 1
+        rows = {n: r for n, r in rows.items() if isinstance(r, dict)}
+        _require(len(rows) > 0, f"onekernel.{lane}",
                  "no rows and no unavailable-reason")
-        for name, row in comp.items():
-            for f in ("onekernel_us", "fused_us", "speedup_vs_fused"):
-                num(row, f, f"onekernel.compiled.{name}")
+        for name, row in rows.items():
+            for f in ("chunk_size", "onekernel_us", "fused_us",
+                      "speedup_vs_fused"):
+                num(row, f, f"onekernel.{lane}.{name}")
+    _require(ran == 1, "onekernel", "exactly one lane (the platform's) "
+             "must carry rows")
     _require(len(report["modes"]) > 0, "modes", "no rows")
     for name, row in report["modes"].items():
         for f in ("items_per_s", "wall_s", "emissions",
@@ -279,29 +264,16 @@ def run() -> list:
                 "items_per_s_onekernel": chunk_size / (us_o / 1e6),
             }
 
-    # Interpret rows are MANDATORY in every environment (they prove the
-    # path runs and track its trajectory) — force the env flag off for
-    # them so a compiled-capable host still records both lanes. Under
-    # jit the interpreter lowers to XLA, so these are honest CPU
-    # numbers; the compiled lane is the TPU claim.
-    saved = os.environ.get("REPRO_PALLAS_COMPILE")
-    os.environ["REPRO_PALLAS_COMPILE"] = "0"
-    try:
-        onekernel_lane("interpret")
-    finally:
-        if saved is None:
-            os.environ.pop("REPRO_PALLAS_COMPILE", None)
-        else:
-            os.environ["REPRO_PALLAS_COMPILE"] = saved
-    report["onekernel"]["interpret"]["note"] = (
-        "interpret-mode Pallas lowered through XLA on this backend; "
-        "CPU-scale numbers — see 'compiled' for the TPU lane")
-    avail, reason = _compiled_lane()
-    if avail:
-        onekernel_lane("compiled")
-    else:
-        report["onekernel"]["compiled"] = {
-            "available": False, "reason": reason}
+    # The platform picks the lane (kernels/ops.interpret_mode); the
+    # other one is recorded as unavailable, with the reason.
+    backend = jax.default_backend()
+    lane, other = (("interpret", "compiled") if kops.interpret_mode()
+                   else ("compiled", "interpret"))
+    onekernel_lane(lane)
+    report["onekernel"][other] = {
+        "available": False,
+        "reason": f"jax backend is {backend!r}; the platform runs the "
+                  f"{lane} lane"}
 
     # --- identical answers (the acceptance contract) ---
     report["answers_identical"] = _assert_answers_identical(
@@ -359,4 +331,6 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -1,5 +1,6 @@
 """Kernel-layer microbench: OASRS ingest + stats pass, jnp path vs the
-Pallas interpret path (correctness-grade on CPU; TPU is the target)."""
+Pallas path — interpreted on a CPU backend, compiled elsewhere
+(``kernels/ops.interpret_mode``); rows name the lane that ran."""
 from __future__ import annotations
 
 import functools
@@ -13,6 +14,10 @@ from repro.core import oasrs, query
 from repro.kernels import ops, ref
 
 SPEC = jax.ShapeDtypeStruct((), jnp.float32)
+
+
+def _lane() -> str:
+    return "interpret" if ops.interpret_mode() else "compiled"
 
 
 def _bench_reservoir_fold(rows):
@@ -46,16 +51,52 @@ def _bench_reservoir_fold(rows):
     rows.append(emit("kernel.reservoir_fold.ref", us_ref,
                      f"items_per_sec={m_ref / (us_ref / 1e6):.0f}"))
 
-    # Pallas interpret mode — correctness path only on CPU; note derived.
     from repro.kernels.reservoir import reservoir_fold
     m_pl = param(2048, 512)
     fold_pl = functools.partial(reservoir_fold, block_m=512,
-                                interpret=True)
+                                interpret=ops.interpret_mode())
     us_pl = time_call(fold_pl, sid[:m_pl], pay[:m_pl], ua[:m_pl],
                       us[:m_pl], mask[:m_pl], st0.counts, st0.capacity,
                       st0.values, warmup=1, iters=3)
-    rows.append(emit("kernel.reservoir_fold.pallas_interpret", us_pl,
-                     "interpret_mode=1 (TPU lowering is the target)"))
+    rows.append(emit(f"kernel.reservoir_fold.pallas_{_lane()}", us_pl,
+                     f"items_per_sec={m_pl / (us_pl / 1e6):.0f}"))
+
+
+def _bench_fold_layout(rows):
+    """``reservoir_fold`` at the network-traffic deployment's widths
+    (8,192-event chunks into 4 intervals x 3 strata of 26,215 slots).
+    That ring is not a whole number of (8, 128) tiles, so each call pads
+    it to 16 x 26,240 and slices the result back; an aligned ring of that
+    size is folded in place. The two rows differ by what the pad costs."""
+    from repro.kernels.reservoir import reservoir_fold
+    m, g, cap = param(8192, 512), 12, 26_215
+    key = jax.random.PRNGKey(11)
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    sid = jax.random.randint(k1, (m,), 0, g)
+    pay = jax.random.normal(k2, (m,))
+    ua = jax.random.uniform(k3, (m,))
+    us = jax.random.uniform(k4, (m,))
+    mask = jnp.ones((m,), jnp.bool_)
+    fold = jax.jit(functools.partial(reservoir_fold, block_m=512,
+                                     interpret=ops.interpret_mode()),
+                   donate_argnums=(5, 7))
+    iters = param(50, 2)
+    for name, (r, n) in (("padded_12x26215", (g, cap)),
+                         ("aligned_16x26240", (16, 26_240))):
+        values = jnp.zeros((r, n), jnp.float32)
+        counts = jnp.zeros((r,), jnp.int32)
+        capacity = jnp.full((r,), cap, jnp.int32)
+        times = []
+        for i in range(2 + iters):
+            t0 = time.perf_counter()
+            values, counts = jax.block_until_ready(
+                fold(sid, pay, ua, us, mask, counts, capacity, values))
+            if i >= 2:
+                times.append(time.perf_counter() - t0)
+        times.sort()
+        us_call = times[len(times) // 2] * 1e6
+        rows.append(emit(f"kernel.reservoir_fold.{name}_{_lane()}", us_call,
+                         f"items_per_sec={m / (us_call / 1e6):.0f}"))
 
 
 def run() -> list:
@@ -82,18 +123,20 @@ def run() -> list:
     rows.append(emit("kernel.stratum_moments.ref", us,
                      f"items_per_sec={m / (us / 1e6):.0f}"))
 
-    # Pallas interpret mode — correctness path only on CPU; note derived.
     small = param(4096, 512)
     us = time_call(
         lambda: ops.stratum_moments(x[:small], sid[:small], s,
                                     use_pallas=True),
         warmup=1, iters=3)
-    rows.append(emit("kernel.stratum_moments.pallas_interpret", us,
-                     "interpret_mode=1 (TPU lowering is the target)"))
+    rows.append(emit(f"kernel.stratum_moments.pallas_{_lane()}", us,
+                     f"items_per_sec={small / (us / 1e6):.0f}"))
 
     _bench_reservoir_fold(rows)
+    _bench_fold_layout(rows)
     return rows
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -210,4 +210,6 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -234,4 +234,6 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -25,6 +25,8 @@ def main(argv=None) -> None:
         # benchmarks.common (module-level sizes read the flag once).
         os.environ["BENCH_SMOKE"] = "1"
 
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (bench_ingest, bench_kernels, bench_obs,
                             bench_scaleout, bench_train, fig5_microbench,
                             fig6_rates_windows, fig7_scale_skew,

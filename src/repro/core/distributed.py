@@ -41,6 +41,20 @@ def _psum(x, axis_names: AxisNames):
     return jax.lax.psum(x, axis_names)
 
 
+def _psum_packed(parts, axis_names: AxisNames) -> list:
+    """ONE psum over several f32 partials: they travel packed in a single
+    flat vector (a tuple psum may lower to one collective per leaf), and
+    come back in their own shapes."""
+    parts = [jnp.asarray(p, jnp.float32) for p in parts]
+    flat = _psum(jnp.concatenate([p.reshape(-1) for p in parts]),
+                 axis_names)
+    out, at = [], 0
+    for p in parts:
+        out.append(flat[at:at + p.size].reshape(p.shape))
+        at += p.size
+    return out
+
+
 def local_update(state: oasrs.OASRSState, stratum_ids: jax.Array,
                  payload, mask=None,
                  backend: Optional[str] = None) -> oasrs.OASRSState:
@@ -77,7 +91,7 @@ def global_mean(local_stats: err.StratumStats, axis_names: AxisNames,
     if alive is None:
         alive = jnp.float32(1.0)
     a = alive.astype(jnp.float32)
-    num, var, cnt, n_alive, n_total = _psum(
+    num, var, cnt, n_alive, n_total = _psum_packed(
         (a * local_sum.value, a * a * local_sum.variance, a * local_count,
          a, jnp.float32(1.0)), axis_names)
     inflate = n_total / jnp.maximum(n_alive, 1.0)
@@ -92,7 +106,7 @@ def _merge_partials(local: err.Estimate, axis_names: AxisNames,
     if alive is None:
         alive = jnp.float32(1.0)
     a = alive.astype(jnp.float32)
-    val, var, n_alive, n_total = _psum(
+    val, var, n_alive, n_total = _psum_packed(
         (a * local.value, a * a * local.variance, a, jnp.float32(1.0)),
         axis_names)
     inflate = n_total / jnp.maximum(n_alive, 1.0)
@@ -105,7 +119,7 @@ def _merge_partials(local: err.Estimate, axis_names: AxisNames,
 # ---------------------------------------------------------------------------
 # Nonlinear queries: single-psum merges of per-shard partial sketches.
 # Each keeps the ingest contract intact — collectives appear only at query
-# time, and each query issues exactly ONE psum (of a small tuple).
+# time, and each query issues exactly ONE psum (of one packed vector).
 # ---------------------------------------------------------------------------
 
 def global_histogram(view, edges: jax.Array, axis_names: AxisNames,
@@ -183,7 +197,8 @@ def global_quantile(view, qs, value_range, axis_names,
         belows = jnp.concatenate([belows, reps[1]])
         totals = jnp.concatenate([totals, reps[2]])
 
-    g_hist, g_below, g_total = _psum((hists, belows, totals), axis_names)
+    g_hist, g_below, g_total = _psum_packed((hists, belows, totals),
+                                            axis_names)
 
     invert = jax.vmap(lambda h, b, t: qt.invert_weighted_cdf(
         h, edges, b, qs * jnp.maximum(t, 1e-20)))
@@ -225,31 +240,32 @@ def gather_cells(view: qt.SampleView, aux: jax.Array,
     identically everywhere; under shard_map each device would otherwise
     only see its OWN shard's).
 
-    Integer payloads travel through ``bitcast_convert_type`` — the
-    collective only moves bytes, so i32/u32 words stay exact (an f32
-    cast would round above 2²⁴).
+    Every word travels as u32 through ``bitcast_convert_type``, so the
+    concatenations and the collective move integer bits only. Packed as
+    f32, a TPU concatenates with a float ``max`` that flushes the small
+    integers' bit patterns (denormals) to zero and rewrites the negative
+    ones (NaNs).
 
     Returns ``(merged_view [W·G, N], aux_all [W, A] u32)``.
     """
     g, n = view.values.shape
-    f32 = jnp.float32
+    u32 = jnp.uint32
     width = n + 2
 
-    def as_f32_col(x):
-        return jax.lax.bitcast_convert_type(
-            x.astype(jnp.int32), f32)[:, None]              # [G, 1]
+    def words(x, dtype):
+        return jax.lax.bitcast_convert_type(x.astype(dtype), u32)
 
     packed = jnp.concatenate(
-        [view.values.astype(f32),
-         as_f32_col(view.counts),
-         as_f32_col(view.taken)], axis=-1)                  # [G, N+2]
+        [words(view.values, jnp.float32),
+         words(view.counts, jnp.int32)[:, None],
+         words(view.taken, jnp.int32)[:, None]], axis=-1)   # [G, N+2]
 
     a = aux.shape[0]
     rows = -(-a // width)
-    aux_f = jax.lax.bitcast_convert_type(aux.astype(jnp.uint32), f32)
-    aux_f = jnp.concatenate(
-        [aux_f, jnp.zeros((rows * width - a,), f32)]).reshape(rows, width)
-    packed = jnp.concatenate([packed, aux_f], axis=0)       # [G+rows, N+2]
+    aux_rows = jnp.concatenate(
+        [aux.astype(u32), jnp.zeros((rows * width - a,), u32)]
+    ).reshape(rows, width)
+    packed = jnp.concatenate([packed, aux_rows], axis=0)    # [G+rows, N+2]
 
     gathered = jax.lax.all_gather(
         packed, axis_name, axis=0, tiled=True)
@@ -257,13 +273,11 @@ def gather_cells(view: qt.SampleView, aux: jax.Array,
 
     cells = gathered[:, :g, :].reshape(num_shards * g, width)
 
-    def back_i32(col):
-        return jax.lax.bitcast_convert_type(col, jnp.int32)
+    def back(x, dtype):
+        return jax.lax.bitcast_convert_type(x, dtype)
 
-    merged = qt.SampleView(values=cells[:, :n],
-                           counts=back_i32(cells[:, n]),
-                           taken=back_i32(cells[:, n + 1]))
-    aux_all = jax.lax.bitcast_convert_type(
-        gathered[:, g:, :].reshape(num_shards, rows * width)[:, :a],
-        jnp.uint32)
+    merged = qt.SampleView(values=back(cells[:, :n], jnp.float32),
+                           counts=back(cells[:, n], jnp.int32),
+                           taken=back(cells[:, n + 1], jnp.int32))
+    aux_all = gathered[:, g:, :].reshape(num_shards, rows * width)[:, :a]
     return merged, aux_all

@@ -75,17 +75,46 @@ class StratumStats:
                          0.0)
 
 
+def _pairwise_sum(x: jax.Array, axis: int) -> jax.Array:
+    """Sum over ``axis`` by pairwise halving, in an order this code fixes.
+
+    ``jnp.sum`` adds in the order the compiler's layout for ``x`` gives,
+    and two programs may lay the same array out differently: on a TPU the
+    mesh and vmap emissions do, and their f32 sums then round apart. The
+    barrier between levels keeps XLA from folding the halvings back into
+    one reduction; within a level each sum has two terms, so any order
+    gives the same bits.
+    """
+    width = 1 << max(x.shape[axis] - 1, 0).bit_length()
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, width - x.shape[axis])
+    x = jnp.pad(x, pad)
+    while width > 1:
+        width //= 2
+        x = jax.lax.optimization_barrier(
+            jax.lax.slice_in_dim(x, 0, width, axis=axis)
+            + jax.lax.slice_in_dim(x, width, 2 * width, axis=axis))
+    return jnp.squeeze(x, axis)
+
+
 def stratum_stats_from_sample(
     xs: jax.Array, counts: jax.Array, taken: jax.Array,
-    slot_mask: jax.Array) -> StratumStats:
-    """Build :class:`StratumStats` from reservoir contents ``xs [S, N]``."""
+    slot_mask: jax.Array, fixed_order: bool = False) -> StratumStats:
+    """Build :class:`StratumStats` from reservoir contents ``xs [S, N]``.
+
+    ``fixed_order`` sums each row with :func:`_pairwise_sum`. Sharded
+    emissions set it: the mesh and vmap placements must agree bitwise,
+    which only a chip run (``chip_smoke.py --mesh4``) can check, since CPU
+    layouts agree either way.
+    """
     m = slot_mask.astype(xs.dtype)
     xs32 = (xs * m).astype(jnp.float32)
+    row_sum = _pairwise_sum if fixed_order else jnp.sum
     return StratumStats(
         counts=counts,
         taken=taken,
-        sums=jnp.sum(xs32, axis=1),
-        sumsqs=jnp.sum(xs32 * xs32 * m.astype(jnp.float32), axis=1),
+        sums=row_sum(xs32, axis=1),
+        sumsqs=row_sum(xs32 * xs32 * m.astype(jnp.float32), axis=1),
     )
 
 

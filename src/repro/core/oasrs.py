@@ -130,23 +130,11 @@ def reset_window(state: OASRSState) -> OASRSState:
 # Batched-model ingestion (Spark-Streaming analog).
 # ---------------------------------------------------------------------------
 
-def _default_interpret() -> bool:
-    """Lazy hop to :func:`repro.kernels.ops.default_interpret` — the one
-    place the ``REPRO_PALLAS_*`` env plumbing lives. Imported inside the
-    function because ``kernels/ops`` imports this module at top level."""
-    from repro.kernels import ops as _kops
-    return _kops.default_interpret()
-
-
 def default_backend() -> str:
     """Chunk-fold backend when the caller passes ``backend=None``: the
-    Pallas kernel on TPU when it actually lowers
-    (``REPRO_PALLAS_COMPILE=1``), the pure-jnp fold everywhere else —
-    the interpret-mode kernel must never land in the hot path by
-    default."""
-    if jax.default_backend() == "tpu" and not _default_interpret():
-        return "pallas"
-    return "jnp"
+    compiled Pallas kernel on TPU, the pure-jnp fold everywhere else —
+    the interpreted kernel never lands in the hot path by default."""
+    return "pallas" if jax.default_backend() == "tpu" else "jnp"
 
 
 def _pallas_eligible(state: OASRSState, payload: Pytree) -> bool:
@@ -269,10 +257,12 @@ def update_chunk(
                 "backend='pallas' needs a single scalar payload leaf "
                 "([M] items into [S, N_max] reservoirs); got payload "
                 f"{jax.tree_util.tree_structure(payload)}")
+        # Imported here: kernels/ops imports this module at top level.
+        from repro.kernels.ops import interpret_mode
         new_values, new_counts = _rk.reservoir_fold(
             stratum_ids.astype(jnp.int32), payload, u_accept, u_slot,
             mask, state.counts, state.capacity, state.values,
-            block_m=block_m, interpret=_default_interpret())
+            block_m=block_m, interpret=interpret_mode())
         return OASRSState(values=new_values, counts=new_counts,
                           capacity=state.capacity, key=key)
     if backend != "jnp":

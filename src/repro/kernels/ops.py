@@ -1,14 +1,14 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this CPU container the kernels run with ``interpret=True`` (Pallas
-executes the kernel body on CPU); on TPU set ``REPRO_PALLAS_COMPILE=1`` to
-lower them for real. ``use_pallas=False`` falls back to the pure-jnp
-reference path (used by default inside big jitted programs where the
-interpreter would be slow).
+Whether a kernel is compiled or interpreted follows the platform, and is
+decided in ONE place (:func:`interpret_mode`): on a CPU backend the
+kernel bodies run under the Pallas interpreter; on any other backend
+they are compiled for real, and a kernel the compiler refuses raises.
+``use_pallas=False`` selects the pure-jnp reference path (used by default
+inside big jitted programs where the interpreter would be slow).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -18,26 +18,15 @@ from repro.core import oasrs
 from repro.core.oasrs import OASRSState
 from repro.kernels import ref
 from repro.kernels import reservoir as _reservoir
-from repro.kernels.reservoir import reservoir_fold
 from repro.kernels.stratified_stats import stratified_stats
 from repro.kernels.weighted_hist import weighted_hist
 
 
-def pallas_compile_enabled() -> bool:
-    """``REPRO_PALLAS_COMPILE=1`` — lower the Pallas kernels for real
-    (TPU). The ONE place the env var is parsed; every kernel wrapper and
-    ``core/oasrs.default_backend`` route through here."""
-    return os.environ.get("REPRO_PALLAS_COMPILE", "0") == "1"
-
-
-def default_interpret() -> bool:
-    """Interpret-mode default shared by ALL kernel wrappers: on this CPU
-    container the kernel bodies run under the Pallas interpreter; set
-    ``REPRO_PALLAS_COMPILE=1`` on TPU to lower them for real."""
-    return not pallas_compile_enabled()
-
-
-_interpret = default_interpret     # single source of truth (this module)
+def interpret_mode() -> bool:
+    """Pallas interpret mode for every kernel call: exactly when JAX's
+    default backend is the CPU. ``core/oasrs`` and every wrapper below
+    route through here."""
+    return jax.default_backend() == "cpu"
 
 
 def stratum_moments(values: jax.Array, stratum_ids: jax.Array,
@@ -48,7 +37,7 @@ def stratum_moments(values: jax.Array, stratum_ids: jax.Array,
         mask = jnp.ones(values.shape, jnp.bool_)
     if use_pallas:
         return stratified_stats(values, stratum_ids, mask, num_strata,
-                                block_m=block_m, interpret=_interpret())
+                                block_m=block_m, interpret=interpret_mode())
     return ref.stratified_stats_ref(values, stratum_ids, mask, num_strata)
 
 
@@ -67,7 +56,7 @@ def weighted_histogram(values: jax.Array, stratum_ids: jax.Array,
     if use_pallas:
         return weighted_hist(values, stratum_ids, weights, mask, edges,
                              num_strata, block_m=block_m,
-                             interpret=_interpret())
+                             interpret=interpret_mode())
     return ref.weighted_hist_ref(values, stratum_ids, weights, mask, edges,
                                  num_strata)
 
@@ -85,12 +74,11 @@ def oasrs_fold(state: OASRSState, stratum_ids: jax.Array,
                               backend="pallas", block_m=block_m)
 
 
-def one_shot_ingest(*args, interpret: Optional[bool] = None, **kwargs):
-    """Interpret-defaulted alias of :func:`reservoir.one_shot_ingest` —
-    the whole accepted-item ingest path (watermark route → slot reset →
+def one_shot_ingest(*args, **kwargs):
+    """:func:`reservoir.one_shot_ingest` in the platform's mode — the
+    whole accepted-item ingest path (watermark route → slot reset →
     (slot, stratum) cell → counter bump → replacement draw → ring write →
     obs counters) as ONE Pallas call. The runtime's
     ``RuntimeConfig.ingest="onekernel"`` path lands here."""
-    if interpret is None:
-        interpret = default_interpret()
-    return _reservoir.one_shot_ingest(*args, interpret=interpret, **kwargs)
+    return _reservoir.one_shot_ingest(*args, interpret=interpret_mode(),
+                                      **kwargs)

@@ -3,18 +3,23 @@
 Folds a chunk of ``M`` records into ``S`` per-stratum reservoirs of width
 ``N`` with *exact sequential* Vitter semantics (Algorithm 1 per stratum).
 
-TPU adaptation (DESIGN.md §2): the reservoirs and counters stay **resident
-in VMEM across grid steps** while item tiles stream in from HBM — the
-classic stationary-accumulator layout. The per-item dependency chain
-(counter → acceptance → slot) is inherently sequential, so the inner body is
-a ``fori_loop`` of scalar updates; its latency is hidden behind the DMA of
-the next item tile (the ingest path is HBM-bandwidth-bound: 8 bytes/item
-streamed vs ~10 scalar ops/item). Randomness (acceptance uniforms and
-replacement-slot uniforms) is precomputed outside with counter-based PRNG so
-the kernel itself is deterministic and replayable.
+TPU layout: the reservoirs stay **resident in VMEM across grid steps**
+while item tiles stream in from HBM — the classic stationary-accumulator
+layout. The per-item dependency chain (counter → acceptance → slot) is
+inherently sequential, so the inner body is a ``fori_loop`` on the scalar
+unit: item tiles and the per-stratum counters live in SMEM, where a
+scalar may be read and written at a dynamic index. VMEM admits only
+vector accesses at tile-aligned offsets, so an accepted item is written
+by a read-select-write of the native ``(8, 128)`` tile that holds its
+slot (:func:`_write_slot`); the wrapper pads the reservoir rows and slots
+to whole tiles so that tile never leaves the buffer. Randomness
+(acceptance uniforms and replacement-slot uniforms) is precomputed
+outside with counter-based PRNG so the kernel itself is deterministic and
+replayable.
 
-The grid walks item tiles; reservoir/counter blocks use constant index maps
-(revisited blocks persist in VMEM — TPU grids are sequential on a core).
+The grid walks item tiles; reservoir/counter blocks use constant index
+maps (revisited blocks persist — TPU grids are sequential on a core).
+Interpret mode is chosen by the caller (``kernels/ops.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -24,40 +29,79 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-# Interpret-mode plumbing (REPRO_PALLAS_COMPILE parsing) lives in ONE
-# place: ``kernels/ops.default_interpret`` — this module's kernels take a
-# plain ``interpret`` flag and never read the environment themselves.
+_LANES = 128
 
-_NEG_TIME = -3.0e38        # f32 -inf stand-in (mirrors runtime/watermark)
-_IMIN = -(2 ** 31) + 1
+
+def _tile_rows(dtype) -> int:
+    """Sublanes of one native VMEM tile for ``dtype`` (8 for 32-bit)."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
+
+
+def _pad_to_tiles(values: jax.Array) -> jax.Array:
+    """Pad a ``[R, N]`` buffer to whole native tiles (no-op if aligned)."""
+    r, n = values.shape
+    rows = _tile_rows(values.dtype)
+    pad_r, pad_n = (-r) % rows, (-n) % _LANES
+    if pad_r or pad_n:
+        values = jnp.pad(values, ((0, pad_r), (0, pad_n)))
+    return values
+
+
+def _write_slot(ref, row, col, value) -> None:
+    """``ref[row, col] = value`` for a VMEM ref, as a read-select-write of
+    the aligned native tile holding the cell (Mosaic stores no scalars to
+    VMEM and loads no unaligned dynamic windows)."""
+    rows = _tile_rows(ref.dtype)
+    r0 = pl.multiple_of((row // rows) * rows, rows)
+    c0 = pl.multiple_of((col // _LANES) * _LANES, _LANES)
+    win = (pl.ds(r0, rows), pl.ds(c0, _LANES))
+    sub = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    hit = (sub == row - r0) & (lane == col - c0)
+    ref[win] = jnp.where(hit, value, ref[win])
+
+
+def _copy_smem(dst, src, rows: int, cols: int) -> None:
+    """Element-wise copy between two ``[rows, cols]`` SMEM refs."""
+    def body(c, _):
+        dst[c // cols, c % cols] = src[c // cols, c % cols]
+        return ()
+    jax.lax.fori_loop(0, rows * cols, body, ())
+
+
+def _vitter_step(c, cap, u, u_slot):
+    """One arrival's Algorithm-1 decision: the ``c``-th arrival of a cell
+    with capacity ``cap`` is accepted while filling, else with
+    probability ``cap / c`` into slot ``floor(u_slot · cap)``."""
+    filling = c <= cap
+    cap_f = cap.astype(jnp.float32)
+    take = filling | (u * c.astype(jnp.float32) < cap_f)
+    rslot = jnp.clip(jnp.floor(u_slot * cap_f).astype(jnp.int32),
+                     0, jnp.maximum(cap - 1, 0))
+    return take, jnp.where(filling, c - 1, rslot)
 
 
 def _fold_kernel(sid_ref, pay_ref, u_ref, uslot_ref, mask_ref,
                  counts_in_ref, cap_ref, values_in_ref,
-                 values_ref, counts_ref, *, block_m: int):
-    step = pl.program_id(0)
-
-    @pl.when(step == 0)
+                 values_ref, counts_ref, *, block_m: int, num_strata: int):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         values_ref[...] = values_in_ref[...]
-        counts_ref[...] = counts_in_ref[...]
+        _copy_smem(counts_ref, counts_in_ref, 1, num_strata)
 
     def body(j, _):
         s = sid_ref[0, j]
-        live = mask_ref[0, j]
+        live = mask_ref[0, j] != 0
         c = counts_ref[0, s] + 1
-        cap = cap_ref[0, s]
-        filling = c <= cap
-        u = u_ref[0, j]
-        accept = live & (filling |
-                         (u * c.astype(jnp.float32) < cap.astype(jnp.float32)))
-        rslot = jnp.floor(
-            uslot_ref[0, j] * cap.astype(jnp.float32)).astype(jnp.int32)
-        rslot = jnp.clip(rslot, 0, jnp.maximum(cap - 1, 0))
-        slot = jnp.where(filling, c - 1, rslot)
-        old = values_ref[s, slot]
-        values_ref[s, slot] = jnp.where(accept, pay_ref[0, j], old)
+        take, slot = _vitter_step(c, cap_ref[0, s], u_ref[0, j],
+                                  uslot_ref[0, j])
+
+        @pl.when(live & take)
+        def _store():
+            _write_slot(values_ref, s, slot, pay_ref[0, j])
+
         counts_ref[0, s] = jnp.where(live, c, c - 1)
         return ()
 
@@ -81,15 +125,17 @@ def reservoir_fold(stratum_ids: jax.Array, payload: jax.Array,
       counts: ``[S]`` int32 running ``C_i``.
       capacity: ``[S]`` int32 ``N_i``.
       values: ``[S, N_max]`` current reservoir payloads.
+      block_m: item tile; a multiple of 128 when compiled for TPU.
 
     Returns:
       ``(new_values [S, N_max], new_counts [S])``. The reservoir and
       counter inputs are aliased to the outputs (``input_output_aliases``)
-      so a donated ring buffer is updated in place — no [S, N_max]
-      re-materialization per chunk.
+      so a donated, tile-aligned reservoir is updated in place; an
+      unaligned one is padded to whole tiles first.
     """
     m = stratum_ids.shape[0]
     s, n_max = values.shape
+    mask = mask.astype(jnp.int32)
     if m % block_m != 0:
         pad = block_m - m % block_m
         stratum_ids = jnp.pad(stratum_ids, (0, pad))
@@ -98,18 +144,20 @@ def reservoir_fold(stratum_ids: jax.Array, payload: jax.Array,
         u_slot = jnp.pad(u_slot, (0, pad))
         mask = jnp.pad(mask, (0, pad))
         m = stratum_ids.shape[0]
+    padded = _pad_to_tiles(values)
     grid = (m // block_m,)
-    item = lambda: pl.BlockSpec((1, block_m), lambda i: (0, i))
-    full_vec = pl.BlockSpec((1, s), lambda i: (0, 0))
-    full_res = pl.BlockSpec((s, n_max), lambda i: (0, 0))
-    kernel = functools.partial(_fold_kernel, block_m=block_m)
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    item = lambda: smem((1, block_m), lambda i: (0, i))
+    full_vec = smem((1, s), lambda i: (0, 0))
+    full_res = pl.BlockSpec(padded.shape, lambda i: (0, 0))
+    kernel = functools.partial(_fold_kernel, block_m=block_m, num_strata=s)
     new_values, new_counts = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[item(), item(), item(), item(), item(),
                   full_vec, full_vec, full_res],
         out_specs=[full_res, full_vec],
-        out_shape=[jax.ShapeDtypeStruct((s, n_max), values.dtype),
+        out_shape=[jax.ShapeDtypeStruct(padded.shape, values.dtype),
                    jax.ShapeDtypeStruct((1, s), jnp.int32)],
         # In-place hot path: reservoirs (input 7) and counters (input 5)
         # alias their outputs, composing with the executors' donated
@@ -118,8 +166,8 @@ def reservoir_fold(stratum_ids: jax.Array, payload: jax.Array,
         interpret=interpret,
     )(stratum_ids[None, :], payload[None, :], u_accept[None, :],
       u_slot[None, :], mask[None, :], counts[None, :], capacity[None, :],
-      values)
-    return new_values, new_counts[0]
+      padded)
+    return new_values[:s, :n_max], new_counts[0]
 
 
 # ---------------------------------------------------------------------------
@@ -145,19 +193,23 @@ class OneShotResult(NamedTuple):
 
 def _one_shot_kernel(*refs, block_m: int, n_pay: int, k: int, s: int,
                      span: float, lateness: float):
-    """Two-phase grid over item tiles; everything else VMEM-pinned.
+    """Two-phase grid over item tiles; everything else pinned on chip.
 
     Phase 0 scans the time/mask tiles to land the post-chunk frontier
     (``max_time``/``open_interval``) — the chunk-level max must be known
     before item 0's eviction verdict, so one pass cannot work. Phase 1
     resets recycled ring slots (tile 0), then streams item tiles through
-    the sequential Vitter fold (the per-item counter → acceptance → slot
-    chain), folding the per-stratum obs counter rows in place; the final
-    tile derives the replacement/occupancy rows from the pre/post cell
-    counts. All ring/counter/accounting blocks use constant index maps —
-    revisited blocks persist in VMEM across the whole grid (TPU grids are
-    sequential on a core) and alias their outputs, so the [K·S, N_max]
-    ring never round-trips to HBM mid-chunk.
+    the sequential Vitter fold (the per-item routing → counter →
+    acceptance → slot chain), folding the per-stratum obs counter rows
+    and the watermark totals item by item; the final tile derives the
+    replacement/occupancy rows from the pre/post cell counts. Item
+    tiles, cell counters, watermark scalars and obs rows live in SMEM
+    (scalar reads and writes at dynamic indices); the ring lives in VMEM
+    and takes tile-aligned vector writes (:func:`_write_slot`). All
+    carried blocks use constant index maps — revisited blocks persist
+    across the whole grid (TPU grids are sequential on a core) and alias
+    their outputs, so the [K·S, N_max] ring never round-trips to HBM
+    mid-chunk.
     """
     times_ref, sid_ref = refs[0], refs[1]
     pay_refs = refs[2:2 + n_pay]
@@ -173,21 +225,29 @@ def _one_shot_kernel(*refs, block_m: int, n_pay: int, k: int, s: int,
     i = pl.program_id(1)
     n_tiles = pl.num_programs(1)
     span_f = jnp.float32(span)
+    i32 = jnp.int32
+
+    def interval_of(t):
+        return jnp.floor(t / span_f).astype(i32)
 
     @pl.when((phase == 0) & (i == 0))
     def _seed_frontier():
-        sf_ref[...] = tin_ref[...]
-        si_ref[...] = iin_ref[...]
+        sf_ref[0, 0] = tin_ref[0, 0]
+        _copy_smem(si_ref, iin_ref, 1, 8)
 
     @pl.when(phase == 0)
     def _scan_frontier():
-        t = times_ref[0, :]
-        mk = mask_ref[0, :]
-        tgt = jnp.floor(t / span_f).astype(jnp.int32)
-        sf_ref[0, 0] = jnp.maximum(
-            sf_ref[0, 0], jnp.max(jnp.where(mk, t, jnp.float32(_NEG_TIME))))
-        si_ref[0, 0] = jnp.maximum(
-            si_ref[0, 0], jnp.max(jnp.where(mk, tgt, jnp.int32(_IMIN))))
+        def body(j, carry):
+            tmax, imax = carry
+            t = times_ref[0, j]
+            live = mask_ref[0, j] != 0
+            return (jnp.where(live, jnp.maximum(tmax, t), tmax),
+                    jnp.where(live, jnp.maximum(imax, interval_of(t)), imax))
+
+        tmax, imax = jax.lax.fori_loop(0, block_m, body,
+                                       (sf_ref[0, 0], si_ref[0, 0]))
+        sf_ref[0, 0] = tmax
+        si_ref[0, 0] = imax
 
     @pl.when(phase == 1)
     def _fold():
@@ -196,100 +256,97 @@ def _one_shot_kernel(*refs, block_m: int, n_pay: int, k: int, s: int,
         wmark = tin_ref[0, 0] - jnp.float32(lateness)  # PRE-chunk watermark
         oldest_live = new_open - jnp.int32(k) + 1
 
+        def desired(slot):
+            # Slot j's desired occupant: the newest live interval
+            # congruent to it mod K.
+            return new_open - jnp.mod(new_open - slot, k)
+
+        def pre_fold_count(c):
+            # A recycled slot zeroes its counts (read from the pristine
+            # input block, so the final tile can re-derive it too).
+            reset = desired(c // s) != siv_ref[0, c // s]
+            return reset, jnp.where(reset, 0, cin_ref[0, c])
+
         @pl.when(i == 0)
         def _reset_ring():
-            # Slot j's desired occupant is the newest live interval
-            # congruent to it mod K; a recycled slot zeroes its counts and
-            # adopts the controller capacity (precomputed, N_max-clamped).
-            cells = jax.lax.broadcasted_iota(jnp.int32, (1, k * s), 1)
-            desired_c = new_open - jnp.mod(new_open - cells // s, k)
-            reset = desired_c != siv_ref[...]
-            cnt_ref[...] = jnp.where(reset, 0, cin_ref[...])
-            cap_ref[...] = jnp.where(reset, adopt_ref[...], capin_ref[...])
-            slots = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
-            des_ref[...] = new_open - jnp.mod(new_open - slots, k)
+            def reset_cell(c, _):
+                reset, c0 = pre_fold_count(c)
+                cnt_ref[0, c] = c0
+                # A reset slot adopts the controller capacity
+                # (precomputed, N_max-clamped).
+                cap_ref[0, c] = jnp.where(reset, adopt_ref[0, c % s],
+                                          capin_ref[0, c])
+                return ()
+
+            jax.lax.fori_loop(0, k * s, reset_cell, ())
+
+            def set_slot(j, _):
+                des_ref[0, j] = desired(j)
+                return ()
+
+            jax.lax.fori_loop(0, k, set_slot, ())
             for vo, vi in zip(vout_refs, vin_refs):
                 vo[...] = vi[...]
-            mout_ref[...] = min_ref[...]
+            _copy_smem(mout_ref, min_ref, 6, s)
             si_ref[0, 5] = si_ref[0, 5] + 1          # obs: chunks folded
 
-        # Vectorized routing + accounting over this tile (the watermark
-        # verdicts are per item, so no sequential dependency here).
-        t = times_ref[0, :]
-        sid = sid_ref[0, :]
-        mk = mask_ref[0, :]
-        tgt = jnp.floor(t / span_f).astype(jnp.int32)
-        acc = mk & ~(t < wmark) & ~(tgt < oldest_live)
-        late_v = acc & (tgt < open_before)
-        strata = jax.lax.broadcasted_iota(jnp.int32, (block_m, s), 1)
-        hot = sid[:, None] == strata                       # [BM, S]
+        def body(j, totals):
+            # Watermark verdict (per item, as route_chunk) → obs rows →
+            # sequential Vitter fold of the (slot, stratum) cell.
+            on_time, late, dropped, items = totals
+            t = times_ref[0, j]
+            sid = sid_ref[0, j]
+            mk = mask_ref[0, j] != 0
+            tgt = interval_of(t)
+            acc = mk & ~(t < wmark) & ~(tgt < oldest_live)
+            late_v = acc & (tgt < open_before)
+            drop = mk & ~acc
+            for row, pred in enumerate((mk, acc, late_v, drop)):
+                mout_ref[row, sid] = mout_ref[row, sid] + pred.astype(i32)
 
-        def rows(pred):
-            return jnp.sum((hot & pred[:, None]).astype(jnp.int32),
-                           axis=0, keepdims=True)          # [1, S]
-
-        mout_ref[0:1, :] = mout_ref[0:1, :] + rows(mk)          # ingested
-        mout_ref[1:2, :] = mout_ref[1:2, :] + rows(acc)         # accepted
-        mout_ref[2:3, :] = mout_ref[2:3, :] + rows(late_v)      # late
-        mout_ref[3:4, :] = mout_ref[3:4, :] + rows(mk & ~acc)   # dropped
-
-        def total(pred):
-            return jnp.sum(pred.astype(jnp.int32))
-
-        si_ref[0, 1] = si_ref[0, 1] + total(acc & (tgt >= open_before))
-        si_ref[0, 2] = si_ref[0, 2] + total(late_v)
-        si_ref[0, 3] = si_ref[0, 3] + total(mk & ~acc)
-        si_ref[0, 4] = si_ref[0, 4] + total(mk)
-
-        # Sequential Vitter fold (counter → acceptance → slot per item);
-        # its latency hides behind the DMA of the next item tile.
-        def body(j, _):
-            tj = times_ref[0, j]
-            tgt_j = jnp.floor(tj / span_f).astype(jnp.int32)
-            live = (mask_ref[0, j] & ~(tj < wmark)
-                    & ~(tgt_j < oldest_live))
-            cell = jnp.mod(tgt_j, k) * s + sid_ref[0, j]
+            cell = jnp.mod(tgt, k) * s + sid
             c = cnt_ref[0, cell] + 1
-            cap = cap_ref[0, cell]
-            filling = c <= cap
-            u = ua_ref[0, j]
-            accept = live & (filling | (u * c.astype(jnp.float32)
-                                        < cap.astype(jnp.float32)))
-            rslot = jnp.floor(
-                us_ref[0, j] * cap.astype(jnp.float32)).astype(jnp.int32)
-            rslot = jnp.clip(rslot, 0, jnp.maximum(cap - 1, 0))
-            slot = jnp.where(filling, c - 1, rslot)
-            for vo, po in zip(vout_refs, pay_refs):
-                old = vo[cell, slot]
-                vo[cell, slot] = jnp.where(accept, po[0, j], old)
-            cnt_ref[0, cell] = jnp.where(live, c, c - 1)
-            return ()
+            take, slot = _vitter_step(c, cap_ref[0, cell], ua_ref[0, j],
+                                      us_ref[0, j])
 
-        jax.lax.fori_loop(0, block_m, body, ())
+            @pl.when(acc & take)
+            def _store():
+                for vo, po in zip(vout_refs, pay_refs):
+                    _write_slot(vo, cell, slot, po[0, j])
+
+            cnt_ref[0, cell] = jnp.where(acc, c, c - 1)
+            return (on_time + (acc & ~late_v).astype(i32),
+                    late + late_v.astype(i32), dropped + drop.astype(i32),
+                    items + mk.astype(i32))
+
+        totals = jax.lax.fori_loop(
+            0, block_m, body,
+            (si_ref[0, 1], si_ref[0, 2], si_ref[0, 3], si_ref[0, 4]))
+        for col, total in enumerate(totals, start=1):
+            si_ref[0, col] = total
 
         @pl.when(i == n_tiles - 1)
         def _finalize_counters():
             # replaced[s] = arrivals that hit a FULL cell; occupancy[s] =
-            # Σ_K min(count, cap) — both from the pre/post-fold counts
-            # (the pre-fold counts are re-derived from the pristine input
-            # block + the reset verdict, which is cheaper than an extra
-            # [1, K·S] scratch output).
-            cells = jax.lax.broadcasted_iota(jnp.int32, (1, k * s), 1)
-            desired_c = new_open - jnp.mod(new_open - cells // s, k)
-            reset = desired_c != siv_ref[...]
-            c0 = jnp.where(reset, 0, cin_ref[...])
-            c1 = cnt_ref[...]
-            cp = cap_ref[...]
-            f0 = jnp.minimum(c0, cp)
-            f1 = jnp.minimum(c1, cp)
-            repl = (c1 - c0) - (f1 - f0)                   # [1, K·S]
-            racc = jnp.zeros((1, s), jnp.int32)
-            occ = jnp.zeros((1, s), jnp.int32)
-            for kk in range(k):                            # static K slices
-                racc = racc + repl[:, kk * s:(kk + 1) * s]
-                occ = occ + f1[:, kk * s:(kk + 1) * s]
-            mout_ref[4:5, :] = mout_ref[4:5, :] + racc     # replaced
-            mout_ref[5:6, :] = occ                         # occupancy gauge
+            # Σ_K min(count, cap) — both from the pre/post-fold counts.
+            def zero(st, _):
+                mout_ref[5, st] = 0
+                return ()
+
+            jax.lax.fori_loop(0, s, zero, ())
+
+            def fold_cell(c, _):
+                _, c0 = pre_fold_count(c)
+                c1 = cnt_ref[0, c]
+                cp = cap_ref[0, c]
+                f0 = jnp.minimum(c0, cp)
+                f1 = jnp.minimum(c1, cp)
+                st = c % s
+                mout_ref[4, st] = mout_ref[4, st] + (c1 - c0) - (f1 - f0)
+                mout_ref[5, st] = mout_ref[5, st] + f1
+                return ()
+
+            jax.lax.fori_loop(0, k * s, fold_cell, ())
 
 
 @functools.partial(
@@ -312,10 +369,10 @@ def one_shot_ingest(times: jax.Array, stratum_ids: jax.Array, payload,
     Fuses watermark routing → interval-ring slot reset → (slot, stratum)
     cell assignment → per-cell counter bump → replacement draw →
     conditional ring write → obs counter fold for an M-item chunk, with
-    item tiles double-buffered from HBM and the [K·S, N_max] ring +
-    counters + accounting pinned in VMEM across tiles (constant index
-    maps + ``input_output_aliases``, extending the ``reservoir_fold``
-    aliasing so the ring never round-trips).
+    item tiles double-buffered from HBM into SMEM, the [K·S, N_max] ring
+    pinned in VMEM and the counters + accounting pinned in SMEM across
+    tiles (constant index maps + ``input_output_aliases``, extending the
+    ``reservoir_fold`` aliasing so the ring never round-trips).
 
     Bitwise contract: identical to the runtime's fused-jnp path —
     routing is ``watermark.route_chunk``'s arithmetic (f32 frontier max,
@@ -369,12 +426,13 @@ def one_shot_ingest(times: jax.Array, stratum_ids: jax.Array, payload,
                 f"payload leaf {pv.shape}/{pv.dtype} does not match "
                 f"items [{m}] / values dtype {vv.dtype}")
 
+    mask = mask.astype(jnp.int32)
     pad = (-m) % block_m
     if pad:
         times = jnp.pad(times, (0, pad))
         stratum_ids = jnp.pad(stratum_ids, (0, pad))
         pay_leaves = [jnp.pad(p, (0, pad)) for p in pay_leaves]
-        mask = jnp.pad(mask, (0, pad))          # pad False: inert items
+        mask = jnp.pad(mask, (0, pad))          # pad 0: inert items
         u_accept = jnp.pad(u_accept, (0, pad))
         u_slot = jnp.pad(u_slot, (0, pad))
     n_tiles = (m + pad) // block_m
@@ -387,32 +445,31 @@ def one_shot_ingest(times: jax.Array, stratum_ids: jax.Array, payload,
         jnp.asarray(late, i32), jnp.asarray(dropped, i32),
         jnp.asarray(items, i32), jnp.asarray(chunks, i32), z, z])[None, :]
     tin = jnp.asarray(max_time, jnp.float32).reshape(1, 1)
-    siv_c = jnp.repeat(slot_interval.astype(i32), s)[None, :]  # per cell
-    adopt_c = jnp.tile(adopt.astype(i32), k)[None, :]          # per cell
     cin = counts.reshape(1, k * s)
     capin = capacity.reshape(1, k * s)
-    vflat = [v.reshape(k * s, n_max) for v in val_leaves]
+    vflat = [_pad_to_tiles(v.reshape(k * s, n_max)) for v in val_leaves]
 
     # Item tiles needed in BOTH phases stream (0, i); fold-only tiles pin
     # to block 0 during phase 0 so the frontier scan fetches no dead DMA.
-    stream = lambda: pl.BlockSpec((1, block_m), lambda p, i: (0, i))
-    foldonly = lambda: pl.BlockSpec((1, block_m), lambda p, i: (0, i * p))
+    smem = functools.partial(pl.BlockSpec, memory_space=pltpu.SMEM)
+    stream = lambda: smem((1, block_m), lambda p, i: (0, i))
+    foldonly = lambda: smem((1, block_m), lambda p, i: (0, i * p))
 
-    def pinned(*shape):
-        return pl.BlockSpec(shape, lambda p, i: (0,) * len(shape))
+    def pinned(*shape, space=pltpu.SMEM):
+        return pl.BlockSpec(shape, lambda p, i: (0,) * len(shape),
+                            memory_space=space)
 
+    rings = [pinned(*v.shape, space=pltpu.VMEM) for v in vflat]
     in_specs = ([stream(), foldonly()]
                 + [foldonly() for _ in range(n_pay)]
                 + [foldonly(), foldonly(), stream(),
-                   pinned(1, 1), pinned(1, 8), pinned(1, k * s),
-                   pinned(1, k * s), pinned(1, k * s), pinned(1, k * s)]
-                + [pinned(k * s, n_max) for _ in range(n_pay)]
-                + [pinned(6, s)])
-    out_specs = ([pinned(k * s, n_max) for _ in range(n_pay)]
+                   pinned(1, 1), pinned(1, 8), pinned(1, k),
+                   pinned(1, s), pinned(1, k * s), pinned(1, k * s)]
+                + rings + [pinned(6, s)])
+    out_specs = (rings
                  + [pinned(1, k * s), pinned(1, k * s), pinned(1, k),
                     pinned(1, 1), pinned(1, 8), pinned(6, s)])
-    out_shape = ([jax.ShapeDtypeStruct((k * s, n_max), v.dtype)
-                  for v in val_leaves]
+    out_shape = ([jax.ShapeDtypeStruct(v.shape, v.dtype) for v in vflat]
                  + [jax.ShapeDtypeStruct((1, k * s), i32),
                     jax.ShapeDtypeStruct((1, k * s), i32),
                     jax.ShapeDtypeStruct((1, k), i32),
@@ -443,13 +500,15 @@ def one_shot_ingest(times: jax.Array, stratum_ids: jax.Array, payload,
     )(times[None, :], stratum_ids[None, :],
       *[p[None, :] for p in pay_leaves],
       u_accept[None, :], u_slot[None, :], mask[None, :],
-      tin, ints_in, siv_c, adopt_c, cin, capin, *vflat, counters)
+      tin, ints_in, slot_interval.astype(i32)[None, :],
+      adopt.astype(i32)[None, :], cin, capin, *vflat, counters)
 
     vout = outs[:n_pay]
     cnt, cap, des, sf, si, mrows = outs[n_pay:]
     return OneShotResult(
         values=jax.tree_util.tree_unflatten(
-            val_def, [o.reshape(k, s, n_max) for o in vout]),
+            val_def, [o[:k * s, :n_max].reshape(k, s, n_max)
+                      for o in vout]),
         counts=cnt.reshape(k, s), capacity=cap.reshape(k, s),
         slot_interval=des[0], max_time=sf[0, 0],
         open_interval=si[0, 0], on_time=si[0, 1], late=si[0, 2],

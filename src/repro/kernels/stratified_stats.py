@@ -2,22 +2,19 @@
 
 This is the per-window hot loop of StreamApprox: every query/error-bound
 evaluation needs per-stratum moments of the sampled (or raw, for the native
-baseline / STS pass 1) items. The TPU adaptation (DESIGN.md §2): a segment
-reduction is re-cast as a *one-hot matmul* so it runs on the MXU instead of
-a scalar scatter loop —
+baseline / STS pass 1) items. A segment reduction is re-cast as a masked
+one-hot reduction on the VPU instead of a scalar scatter loop, with items
+on lanes so no lane-to-sublane relayout is needed —
 
-    onehot[j, s] = (sid[j] == s) & mask[j]          (VPU compare)
-    counts += 1ᵀ·onehot;  sums += xᵀ·onehot;  sumsqs += (x²)ᵀ·onehot  (MXU)
+    onehot[s, j] = (sid[j] == s) & mask[j]                 ([S, BM], VPU)
+    counts[s] += Σ_j onehot[s, j];  sums[s] += Σ_j onehot[s, j]·x[j]; …
 
-The item axis is tiled with ``block_m``; the three ``[1, S]`` accumulators
+The item axis is tiled with ``block_m``; the three ``[S, 1]`` accumulators
 live in VMEM across sequential grid steps (TPU grids execute in order on a
-core, so revisited output blocks act as accumulators). Arithmetic intensity:
-3·S FLOPs per item-byte — compute-bound on the MXU for S ≥ 64, which is why
-this beats the HBM-bound scatter formulation.
-
-Interpret-vs-compiled is NOT decided here: callers (``kernels/ops``)
-pass ``interpret=ops.default_interpret()`` — the single
-``REPRO_PALLAS_COMPILE`` parse shared by every kernel wrapper.
+core, so revisited output blocks act as accumulators). The mask travels
+as int32 (Mosaic has no i1 memory blocks). The sums stay in f32 on the
+VPU: an MXU matmul would round ``x`` to bf16 at default precision.
+Interpret mode is chosen by the caller (``kernels/ops.interpret_mode``).
 """
 from __future__ import annotations
 
@@ -38,20 +35,15 @@ def _stats_kernel(x_ref, sid_ref, mask_ref, counts_ref, sums_ref,
         sums_ref[...] = jnp.zeros_like(sums_ref)
         sumsqs_ref[...] = jnp.zeros_like(sumsqs_ref)
 
-    x = x_ref[0, :].astype(jnp.float32)                       # [BM]
-    sid = sid_ref[0, :]                                       # [BM]
-    mask = mask_ref[0, :]                                     # [BM]
-    strata = jax.lax.broadcasted_iota(jnp.int32, (x.shape[0], num_strata), 1)
-    onehot = ((sid[:, None] == strata) & mask[:, None]).astype(jnp.float32)
-
-    ones = jnp.ones((1, x.shape[0]), jnp.float32)
-    xm = (x * mask.astype(jnp.float32))[None, :]              # [1, BM]
-    counts_ref[...] += jnp.dot(ones, onehot,
-                               preferred_element_type=jnp.float32)
-    sums_ref[...] += jnp.dot(xm, onehot,
-                             preferred_element_type=jnp.float32)
-    sumsqs_ref[...] += jnp.dot(xm * x[None, :], onehot,
-                               preferred_element_type=jnp.float32)
+    x = x_ref[...].astype(jnp.float32)                        # [1, BM]
+    strata = jax.lax.broadcasted_iota(
+        jnp.int32, (num_strata, x.shape[1]), 0)               # [S, BM]
+    onehot = ((sid_ref[...] == strata) & (mask_ref[...] != 0)
+              ).astype(jnp.float32)
+    xm = onehot * x
+    counts_ref[...] += jnp.sum(onehot, axis=1, keepdims=True)
+    sums_ref[...] += jnp.sum(xm, axis=1, keepdims=True)
+    sumsqs_ref[...] += jnp.sum(xm * x, axis=1, keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("num_strata", "block_m",
@@ -73,6 +65,7 @@ def stratified_stats(values: jax.Array, stratum_ids: jax.Array,
       ``(counts, sums, sumsqs)`` — each ``[S]`` float32.
     """
     m = values.shape[0]
+    mask = mask.astype(jnp.int32)
     if m % block_m != 0:
         pad = block_m - m % block_m
         values = jnp.pad(values, (0, pad))
@@ -81,9 +74,9 @@ def stratified_stats(values: jax.Array, stratum_ids: jax.Array,
         m = values.shape[0]
     grid = (m // block_m,)
     kernel = functools.partial(_stats_kernel, num_strata=num_strata)
-    out_shape = [jax.ShapeDtypeStruct((1, num_strata), jnp.float32)] * 3
+    out_shape = [jax.ShapeDtypeStruct((num_strata, 1), jnp.float32)] * 3
     item_spec = pl.BlockSpec((1, block_m), lambda i: (0, i))
-    acc_spec = pl.BlockSpec((1, num_strata), lambda i: (0, 0))
+    acc_spec = pl.BlockSpec((num_strata, 1), lambda i: (0, 0))
     counts, sums, sumsqs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -92,4 +85,4 @@ def stratified_stats(values: jax.Array, stratum_ids: jax.Array,
         out_shape=out_shape,
         interpret=interpret,
     )(values[None, :], stratum_ids[None, :], mask[None, :])
-    return counts[0], sums[0], sumsqs[0]
+    return counts[:, 0], sums[:, 0], sumsqs[:, 0]

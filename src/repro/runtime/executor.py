@@ -426,8 +426,16 @@ def _merged_view(cfg: RuntimeConfig, state: RuntimeState,
                              counts=views.counts.reshape(-1),
                              taken=views.taken.reshape(-1))
         aux = None
+    if cfg.num_shards > 1:
+        # Both placements hand the estimators the same [W·K·S] view.
+        # Without the barrier XLA folds the vmap path's reshape into the
+        # reductions that follow (summing [W, K, S] cells, or the mesh's
+        # [W, K·S] gather rows), and on a TPU a reduction of another
+        # shape rounds f32 sums differently.
+        view = jax.lax.optimization_barrier(view)
     stats = err.stratum_stats_from_sample(
-        view.values, view.counts, view.taken, view.slot_mask())
+        view.values, view.counts, view.taken, view.slot_mask(),
+        fixed_order=cfg.num_shards > 1)
     return view, stats, aux
 
 
@@ -531,7 +539,8 @@ def _evaluate_interval(cfg: RuntimeConfig, registry: QueryRegistry,
     iview = win.restrict_view(view, _interval_cell_mask(cfg, state,
                                                         interval, aux))
     istats = err.stratum_stats_from_sample(
-        iview.values, iview.counts, iview.taken, iview.slot_mask())
+        iview.values, iview.counts, iview.taken, iview.slot_mask(),
+        fixed_order=cfg.num_shards > 1)
     key = jax.random.fold_in(base_key, interval)
     results = registry.evaluate_view(iview, istats, key, ctx=ctx)
     return results, istats
@@ -743,19 +752,19 @@ class _ExecutorBase:
 
         Arguments are ``n_sharded`` leading-[W]-sharded pytrees followed
         by ``n_replicated`` replicated ones; outputs likewise.
-        ``check_rep=False`` is required for the scan bodies on the
-        pinned jax 0.4.37.
+        ``check_vma=False``: the replicated outputs are replicated by
+        construction (every device merges the same gathered cells), which
+        the varying-axes check cannot infer statically.
         """
         if self._mesh is None:
             return fn
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         a = P(self._axis)
         in_specs = (a,) * n_sharded + (P(),) * n_replicated
         outs = (a,) * out_sharded + (P(),) * out_replicated
-        return shard_map(fn, mesh=self._mesh, in_specs=in_specs,
-                         out_specs=outs[0] if len(outs) == 1 else outs,
-                         check_rep=False)
+        return jax.shard_map(fn, mesh=self._mesh, in_specs=in_specs,
+                             out_specs=outs[0] if len(outs) == 1 else outs,
+                             check_vma=False)
 
     def _sentinel(self, name: str, allowed: int) -> RetraceSentinel:
         s = RetraceSentinel(f"{self.mode}.{name}", allowed=allowed,
@@ -1018,13 +1027,12 @@ class BatchedExecutor(_ExecutorBase):
             if self._mesh is not None:
                 # Stacked micro-batch leaves are [B, W, M]: the scan axis
                 # stays whole, the shard axis splits one row per device.
-                from jax.experimental.shard_map import shard_map
                 from jax.sharding import PartitionSpec as P
                 a = P(self._axis)
-                inner = shard_map(
+                inner = jax.shard_map(
                     body_fn, mesh=self._mesh,
                     in_specs=(a, P(None, self._axis), P()),
-                    out_specs=(a, P()), check_rep=False)
+                    out_specs=(a, P()), check_vma=False)
 
             def step(state, stacked, latency_prev):
                 sentinel.trace()

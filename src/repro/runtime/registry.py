@@ -250,9 +250,11 @@ class QueryRegistry:
         kinds group by — merged-only registries never need it.
         """
         out: Dict[str, Result] = {}
+        sharded = ctx is not None and ctx.num_shards > 1
         for q in self._queries:
             if q.window == "merged":
-                out[q.name] = self._eval_merged(q, view, stats, key)
+                out[q.name] = self._eval_merged(q, view, stats, key,
+                                                sharded)
             else:
                 if ctx is None:
                     raise ValueError(
@@ -264,7 +266,8 @@ class QueryRegistry:
         return out
 
     def _eval_merged(self, q: StandingQuery, view: qt.SampleView,
-                     stats: err.StratumStats, key: jax.Array) -> Result:
+                     stats: err.StratumStats, key: jax.Array,
+                     sharded: bool = False) -> Result:
         if q.kind == "sum":
             return err.estimate_sum(stats)
         if q.kind == "mean":
@@ -273,7 +276,8 @@ class QueryRegistry:
             ind = q.predicate(view.values).astype(jnp.float32)
             return err.estimate_sum(
                 err.stratum_stats_from_sample(
-                    ind, view.counts, view.taken, view.slot_mask()))
+                    ind, view.counts, view.taken, view.slot_mask(),
+                    fixed_order=sharded))
         if q.kind == "histogram":
             return qt.cell_counts(view, jnp.asarray(q.edges, jnp.float32))
         if q.kind == "quantile":
@@ -307,8 +311,10 @@ class QueryRegistry:
         else:
             base = view
         gid = ctx.key_of_cell(base.counts.shape[0])
+        sharded = ctx.num_shards > 1
         gstats = err.stratum_stats_from_sample(
-            base.values, base.counts, base.taken, base.slot_mask())
+            base.values, base.counts, base.taken, base.slot_mask(),
+            fixed_order=sharded)
         if q.kind == "sum":
             return err.estimate_sum_grouped(gstats, gid, s)
         if q.kind == "mean":
@@ -317,7 +323,8 @@ class QueryRegistry:
             ind = q.predicate(base.values).astype(jnp.float32)
             return err.estimate_sum_grouped(
                 err.stratum_stats_from_sample(
-                    ind, base.counts, base.taken, base.slot_mask()),
+                    ind, base.counts, base.taken, base.slot_mask(),
+                    fixed_order=sharded),
                 gid, s)
         assert q.kind == "quantile"
         # Per-key stratified bootstrap: each key keeps its own cells and

@@ -1,13 +1,36 @@
-"""Shared small utilities: PRNG plumbing, ranking, tree helpers."""
+"""Shared small utilities: PRNG plumbing, ranking, tree helpers, the
+persistent compile cache."""
 from __future__ import annotations
 
 import dataclasses
+import os
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 
 Pytree = Any
+
+
+#: Fixed cache location inside the checkout (git-ignored): the path is
+#: part of what lets a later run on the same checkout find its entries.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses that
+    directory and nothing is set here. Otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`. Call before the first compilation.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def tree_leading_dim(tree: Pytree) -> int:

@@ -3,9 +3,7 @@ import sys
 
 # Tests run on CPU, but the scale-out suite needs a real (simulated)
 # device mesh: force 8 host CPU devices BEFORE jax initializes its
-# backend.  This is the only supported lever on the pinned jax 0.4.37
-# (there is no jax_num_cpu_devices config there), and it must be merged
-# with any XLA_FLAGS the caller already set.
+# backend, merged with any XLA_FLAGS the caller already set.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:
@@ -22,15 +20,3 @@ import pytest
 def key():
     return jax.random.PRNGKey(0)
 
-
-def abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across JAX API generations (shared test helper).
-
-    jax <= 0.4.x takes one ``((name, size), ...)`` shape tuple; newer
-    releases take ``(axis_sizes, axis_names)`` positionally.
-    """
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))

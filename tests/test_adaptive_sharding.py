@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import abstract_mesh as _abstract_mesh
+from jax.sharding import AbstractMesh
 from repro import configs as cfgs
 from repro.core import adaptive, error as err
 from repro.distributed import sharding as shd
@@ -52,7 +52,7 @@ def test_throughput_budget():
 # Attention/MoE TP mode selection (DESIGN.md §6)
 # ---------------------------------------------------------------------------
 
-MESH = _abstract_mesh((16, 16), ("data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
 
 EXPECTED_MODE = {
     # kv divisible → kv_heads; else G divisible → q_group; else seq
@@ -92,7 +92,7 @@ def test_resolve_spec_divisibility():
                             (256, 4096, 8, 128), MESH, rules)
     assert spec[2] is None
     # batch folds pod+data when present
-    mesh3 = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mesh3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     spec3 = shd.resolve_spec(("batch", None), (256, 10), mesh3, rules)
     assert spec3[0] == ("pod", "data")
 

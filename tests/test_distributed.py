@@ -33,9 +33,8 @@ def test_sts_pass1_has_collective(key):
 
     mesh = jax.make_mesh((1,), ("data",))
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(counts_fn, mesh=mesh, in_specs=P("data"),
-                   out_specs=P())
+    fn = jax.shard_map(counts_fn, mesh=mesh, in_specs=P("data"),
+                       out_specs=P())
     jaxpr = str(jax.make_jaxpr(fn)(jnp.zeros((16,), jnp.int32)))
     assert "psum" in jaxpr
 
@@ -91,7 +90,6 @@ def test_straggler_drop_unbiased(key):
 
 def test_merge_partials_inflation_math():
     """_merge_partials under shard_map with an alive mask."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("data",))
 
@@ -100,8 +98,8 @@ def test_merge_partials_inflation_math():
         out = dist._merge_partials(local, "data", alive[0])
         return jnp.stack([out.value, out.variance])
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=P())
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P("data"), P("data")), out_specs=P())
     out = fn(jnp.array([5.0]), jnp.array([1.0]))
     assert float(out[0]) == 5.0 and float(out[1]) == 1.0
 

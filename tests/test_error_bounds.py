@@ -29,6 +29,23 @@ def test_var_formulas_against_numpy():
     np.testing.assert_allclose(err.var_mean(stats), exp_mean, rtol=1e-4)
 
 
+@pytest.mark.parametrize("width", [1, 7, 64, 1000])
+@pytest.mark.parametrize("fixed_order", [False, True])
+def test_stratum_moments_match_float64(width, fixed_order):
+    """Both row-sum orders give the masked moments of a float64 sum."""
+    rng = np.random.default_rng(width)
+    xs = rng.normal(3, 5, (4, width)).astype(np.float32)
+    taken = np.array([width, width // 2, 1, 0], np.int32)
+    mask = np.arange(width)[None, :] < taken[:, None]
+    stats = err.stratum_stats_from_sample(
+        jnp.asarray(xs), jnp.full((4,), 2 * width, jnp.int32),
+        jnp.asarray(taken), jnp.asarray(mask), fixed_order=fixed_order)
+    x64 = np.where(mask, xs, 0.0).astype(np.float64)
+    np.testing.assert_allclose(stats.sums, x64.sum(1), rtol=1e-5,
+                               atol=1e-4)
+    np.testing.assert_allclose(stats.sumsqs, (x64 ** 2).sum(1), rtol=1e-5)
+
+
 def test_full_take_is_exact(key):
     """C_i <= N_i ⇒ estimator equals the exact value, variance 0."""
     sid = jax.random.randint(key, (100,), 0, 4)

@@ -351,14 +351,15 @@ def test_onekernel_checkpoint_roundtrip(key):
 # ---------------------------------------------------------------------------
 
 def test_default_interpret_single_source(monkeypatch):
-    """kernels/ops owns the REPRO_PALLAS_* parsing; oasrs and the
-    kernel wrappers all route through it."""
+    """kernels/ops owns the one platform rule — interpret exactly on a
+    CPU backend — and oasrs's default fold backend follows it: the
+    compiled kernel on TPU, the jnp fold elsewhere."""
     from repro.core import oasrs
-    monkeypatch.delenv("REPRO_PALLAS_COMPILE", raising=False)
-    assert kops.default_interpret() is True
-    assert oasrs._default_interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_COMPILE", "1")
-    assert kops.default_interpret() is False
-    assert kops.pallas_compile_enabled() is True
-    assert oasrs._default_interpret() is False
+    for platform, interpret, backend in (("cpu", True, "jnp"),
+                                         ("tpu", False, "pallas"),
+                                         ("gpu", False, "jnp")):
+        monkeypatch.setattr(jax, "default_backend", lambda: platform)
+        assert kops.interpret_mode() is interpret
+        assert oasrs.default_backend() == backend
+    assert not hasattr(kops, "pallas_compile_enabled")
     assert not hasattr(rk, "default_interpret")   # hoisted out of reservoir

@@ -9,7 +9,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import distributed as dist
@@ -142,8 +141,9 @@ def test_distributed_quantile_matches_single_shard(key):
                                    key=jax.random.PRNGKey(9))
         return est.value, est.variance
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P("data"), P("data")),
+                       out_specs=P(), check_vma=False)
     v, var = jax.jit(fn)(sid, x)
     # single-shard reference: identical state (same key), sort estimator
     st = oasrs.update_chunk(oasrs.init(3, 256, SPEC, jax.random.PRNGKey(7)),

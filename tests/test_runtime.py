@@ -489,7 +489,6 @@ def test_sharded_ingest_has_no_collectives(key):
 def test_sharded_stats_merge_matches_global_psum(key):
     """The executor's Eq. 5 shard merge equals the single-psum merge in
     core/distributed.py run under shard_map."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.runtime.executor import _merged_view
 
@@ -501,7 +500,7 @@ def test_sharded_stats_merge_matches_global_psum(key):
     local = err.estimate_sum(stats)
 
     mesh = jax.make_mesh((1,), ("data",))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda s: jnp.stack(
             [dist.global_sum(s, "data").value,
              dist.global_sum(s, "data").variance]),

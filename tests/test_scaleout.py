@@ -7,6 +7,7 @@ forces ``--xla_force_host_platform_device_count=8`` before the first
 jax import, so the whole file runs on the CPU container.
 """
 import dataclasses
+import re
 from types import SimpleNamespace
 
 import jax
@@ -150,11 +151,14 @@ def test_mesh_ingest_is_collective_free(key):
 
 def test_mesh_emission_single_gather(key):
     """Each mesh emission performs exactly ONE collective: the tiled
-    all_gather in dist.gather_cells (samples + aux ride together)."""
+    all_gather in dist.gather_cells (samples + aux ride together), of
+    u32 words — a TPU may rewrite integer bit patterns packed as f32."""
     ex = PipelinedExecutor(_cfg(4, "mesh"), _registry(), key)
     jaxpr = str(jax.make_jaxpr(
         lambda s, t: ex._emit(s, t))(ex.state, jnp.float32(0.01)))
     assert jaxpr.count("all_gather[") == 1, "emission must merge once"
+    assert re.search(r":u32\[[\d,]*\] = all_gather\[", jaxpr), (
+        "the gather must move u32 words")
     for prim in ("psum", "all_reduce", "ppermute", "all_to_all"):
         assert prim not in jaxpr, f"extra collective {prim} in emission"
 

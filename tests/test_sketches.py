@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import distributed as dist
@@ -138,8 +137,9 @@ def test_global_key_counts_single_psum_matches_local(key):
         est = dist.global_key_counts(qt.sample_view(st), keys, "data")
         return est.value, est.variance
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P("data"), P("data")),
+                       out_specs=P(), check_vma=False)
     v, var = jax.jit(fn)(sid, x)
     st = oasrs.update_chunk(oasrs.init(2, 128, SPEC, jax.random.PRNGKey(3)),
                             sid, x)
@@ -166,8 +166,9 @@ def test_global_histogram_matches_local(key):
         est = dist.global_histogram(qt.sample_view(st), edges, "data")
         return est.value, est.variance
 
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                   out_specs=P(), check_rep=False)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P("data"), P("data")),
+                       out_specs=P(), check_vma=False)
     v, var = jax.jit(fn)(sid, x)
     st = oasrs.update_chunk(oasrs.init(3, 128, SPEC, jax.random.PRNGKey(5)),
                             sid, x)

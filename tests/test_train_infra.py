@@ -50,9 +50,9 @@ def test_master_weights_fp32(key):
 def test_zero_pspec_folds_dp_axes():
     from jax.sharding import PartitionSpec as P
 
-    from conftest import abstract_mesh
+    from jax.sharding import AbstractMesh
     # abstract mesh: zero_pspec only reads axis sizes
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     spec = opt.zero_pspec(P(None, "model"), (64, 32), mesh, ("data",))
     assert spec == P("data", "model")
     # non-divisible first dim falls through to the next dim
@@ -143,11 +143,11 @@ def test_window_deadline():
 # ---------------------------------------------------------------------------
 
 def _run_sharded(fn, *args):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1,), ("pod",))
-    return shard_map(fn, mesh=mesh,
-                     in_specs=tuple(P() for _ in args), out_specs=P())(*args)
+    return jax.shard_map(fn, mesh=mesh,
+                         in_specs=tuple(P() for _ in args),
+                         out_specs=P())(*args)
 
 
 def test_psum_int8_accuracy(key):
@@ -164,11 +164,10 @@ def test_psum_bf16_accuracy(key):
 
 
 def test_hierarchical_sync_single_device(key):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     mesh = jax.make_mesh((1, 1), ("pod", "data"))
     g = jax.random.normal(key, (64,))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda x: compression.hierarchical_grad_sync(x, "data", "pod",
                                                      "int8"),
         mesh=mesh, in_specs=P(), out_specs=P())
