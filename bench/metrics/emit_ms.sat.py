@@ -1,0 +1,7 @@
+"""Emission, closed loop: host milliseconds per emission, from pushes that
+fired emissions less the median push that fired none."""
+from _common import emit_ms
+
+
+def read(ctx):
+    return emit_ms(ctx.window)
