@@ -18,7 +18,7 @@ Two claims from the deployment story get numbers here:
   exactly-once continuity the crash harness proves bitwise).
 
 Writes schema-validated ``BENCH_scaleout.json`` (to ``$BENCH_OUT`` or
-the CWD) in every lane — a CI artifact alongside BENCH_ingest/BENCH_obs.
+the CWD) in every lane — a CI artifact alongside BENCH_ingest.
 """
 from __future__ import annotations
 

@@ -27,12 +27,11 @@ def main(argv=None) -> None:
 
     from repro.utils import enable_compile_cache
     enable_compile_cache()
-    from benchmarks import (bench_ingest, bench_kernels, bench_obs,
-                            bench_scaleout, bench_train, fig5_microbench,
-                            fig6_rates_windows, fig7_scale_skew,
-                            fig8_means_over_time, fig9_network_traffic,
-                            fig10_taxi, fig_emission, fig_quantiles,
-                            fig_recovery, fig_runtime_modes)
+    from benchmarks import (bench_ingest, bench_kernels, bench_scaleout,
+                            bench_train, fig5_microbench, fig6_rates_windows,
+                            fig7_scale_skew, fig8_means_over_time,
+                            fig9_network_traffic, fig10_taxi, fig_emission,
+                            fig_quantiles, fig_recovery, fig_runtime_modes)
     modules = [
         ("fig5(a-c) microbenchmarks", fig5_microbench),
         ("fig6 arrival rates + windows", fig6_rates_windows),
@@ -46,7 +45,6 @@ def main(argv=None) -> None:
         ("emission: staleness, cadence vs watermark", fig_emission),
         ("ingest hot path: fused vs masked-vmap vs one-kernel", bench_ingest),
         ("scale-out: mesh throughput + elastic rescale", bench_scaleout),
-        ("observability: telemetry overhead", bench_obs),
         ("kernel bench", bench_kernels),
         ("training-plane bench", bench_train),
     ]

@@ -56,6 +56,7 @@ from repro.kernels import ops as kops
 from repro.core import quantile as qt
 from repro.core import window as win
 from repro.obs import metrics as obm
+from repro.obs import spans as obs_spans
 from repro.obs.sentinel import RetraceSentinel
 from repro.runtime import checkpoint as ckp
 from repro.runtime import controller as ctl
@@ -907,26 +908,30 @@ class _ExecutorBase:
                     "interval's sample was recycled unemitted — grow "
                     "num_intervals or shorten the chunk/micro-batch "
                     "event span")
-            self.state, results = self._emit_interval_fn(
-                self.state, jnp.int32(j), self._emit_base_key,
-                jnp.float32(latency_s))
-            jax.block_until_ready(results)
-            self._record(results, latency_s, interval=j)
+            with obs_spans.span(obs_spans.EMIT):
+                self.state, results = self._emit_interval_fn(
+                    self.state, jnp.int32(j), self._emit_base_key,
+                    jnp.float32(latency_s))
+                jax.block_until_ready(results)
+                self._record(results, latency_s, interval=j)
             self._emitted_through = j
             emitted += 1
         return emitted
 
     def _record(self, results, latency_s: float,
                 interval: Optional[int] = None) -> Emission:
-        wmark, open_iv, on_time, late, dropped = self._wm_totals(self.state)
-        cap = self.state.ctrl.capacity
-        if self.cfg.num_shards > 1:
-            cap = jnp.sum(cap, axis=0)     # global capacity = Σ shard caps
-        # Materialize: the recorded capacity must not reference the live
-        # state buffer — the next compiled step DONATES the state, which
-        # would delete the emission's array out from under the consumer.
-        # (Emissions are host records; this is the host sync boundary.)
-        cap = np.asarray(cap)
+        with obs_spans.span(obs_spans.READBACK):
+            wmark, open_iv, on_time, late, dropped = self._wm_totals(
+                self.state)
+            cap = self.state.ctrl.capacity
+            if self.cfg.num_shards > 1:
+                cap = jnp.sum(cap, axis=0)  # global capacity = Σ shard caps
+            # Materialize: the recorded capacity must not reference the
+            # live state buffer — the next compiled step DONATES the
+            # state, which would delete the emission's array out from
+            # under the consumer. (Emissions are host records; this is
+            # the host sync boundary.)
+            cap = np.asarray(cap)
         # The index comes from the monotonic cursor, NOT len(emissions):
         # a restored executor's emissions list restarts empty but its
         # cursor continues from the checkpoint, so re-emitted suffix
@@ -1170,38 +1175,42 @@ class PipelinedExecutor(_ExecutorBase):
         self._emit_t0 = time.perf_counter()
 
     def push(self, chunk: TimestampedChunk) -> None:
-        if self._chunks_since_emit == 0:
-            # The emission period's latency clock starts at its FIRST
-            # arrival — idle wall time between periods (or before the
-            # first chunk ever) must not read as processing latency.
-            self._emit_t0 = time.perf_counter()
-        if self._mesh is not None:
-            from repro.runtime import records
-            chunk = records.place_sharded(chunk, self._mesh)
-        self.state = self._step(self.state, chunk)     # async dispatch
-        self._items_since_emit += int(chunk.values.size)
-        self._chunks_since_emit += 1
-        self.chunks_pushed += 1
-        if self.cfg.emission == "watermark":
-            # The emit decision reads ONLY the chunk's own buffers (host
-            # frontier mirror) — between closes the loop stays
-            # dispatch-only, no sync on the in-flight state.
-            self._advance_frontier(chunk)
-            if self._closed_through() > self._emitted_through:
-                jax.block_until_ready(self.state)   # emission boundary
-                elapsed = time.perf_counter() - self._emit_t0
-                per_chunk = elapsed / max(self._chunks_since_emit, 1)
-                self._last_latency = per_chunk
-                self._emit_closed(per_chunk)
-                self._chunks_since_emit = 0
+        with obs_spans.span(obs_spans.PUSH):
+            if self._chunks_since_emit == 0:
+                # The emission period's latency clock starts at its FIRST
+                # arrival — idle wall time between periods (or before the
+                # first chunk ever) must not read as processing latency.
                 self._emit_t0 = time.perf_counter()
-        elif self._chunks_since_emit >= self.cfg.emit_every:
-            self._emit_now()
-        if self.checkpointer is not None:
-            # Cadence boundary only: capture() blocks on the state, but
-            # the per-push hot path above stays dispatch-only (trace
-            # count and jaxpr asserted unchanged in tests).
-            self.checkpointer.maybe(self)
+            with obs_spans.span(obs_spans.DISPATCH):
+                if self._mesh is not None:
+                    from repro.runtime import records
+                    chunk = records.place_sharded(chunk, self._mesh)
+                self.state = self._step(self.state, chunk)  # async dispatch
+            self._items_since_emit += int(chunk.values.size)
+            self._chunks_since_emit += 1
+            self.chunks_pushed += 1
+            if self.cfg.emission == "watermark":
+                # The emit decision reads ONLY the chunk's own buffers
+                # (host frontier mirror) — between closes the loop stays
+                # dispatch-only, no sync on the in-flight state.
+                with obs_spans.span(obs_spans.FRONTIER):
+                    self._advance_frontier(chunk)
+                    closing = self._closed_through() > self._emitted_through
+                if closing:
+                    jax.block_until_ready(self.state)  # emission boundary
+                    elapsed = time.perf_counter() - self._emit_t0
+                    per_chunk = elapsed / max(self._chunks_since_emit, 1)
+                    self._last_latency = per_chunk
+                    self._emit_closed(per_chunk)
+                    self._chunks_since_emit = 0
+                    self._emit_t0 = time.perf_counter()
+            elif self._chunks_since_emit >= self.cfg.emit_every:
+                self._emit_now()
+            if self.checkpointer is not None:
+                # Cadence boundary only: capture() blocks on the state,
+                # but the per-push hot path above stays dispatch-only
+                # (trace count and jaxpr asserted unchanged in tests).
+                self.checkpointer.maybe(self)
 
     def _emit_now(self) -> None:
         # Emission boundary — the ONLY place the pipeline touches host.
@@ -1209,10 +1218,11 @@ class PipelinedExecutor(_ExecutorBase):
         elapsed = time.perf_counter() - self._emit_t0
         per_chunk = elapsed / max(self._chunks_since_emit, 1)
         self._last_latency = per_chunk
-        self.state, results = self._emit(self.state,
-                                         jnp.float32(per_chunk))
-        jax.block_until_ready(results)
-        self._record(results, per_chunk)
+        with obs_spans.span(obs_spans.EMIT):
+            self.state, results = self._emit(self.state,
+                                             jnp.float32(per_chunk))
+            jax.block_until_ready(results)
+            self._record(results, per_chunk)
         self._chunks_since_emit = 0
         self._emit_t0 = time.perf_counter()
 
