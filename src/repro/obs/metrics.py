@@ -219,7 +219,8 @@ class Telemetry:
                       interval_span=cfg.interval_span,
                       allowed_lateness=cfg.allowed_lateness,
                       num_shards=cfg.num_shards,
-                      queries=describe(ex.registry))
+                      queries=describe(ex.registry),
+                      emit_cells=ex.emit_cells())
 
     def on_emission(self, ex, em) -> None:
         """One emission was recorded (the host just blocked on results)."""

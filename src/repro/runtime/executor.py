@@ -491,33 +491,49 @@ def _evaluate(cfg: RuntimeConfig, registry: QueryRegistry,
     return results, stats
 
 
-def _interval_cell_mask(cfg: RuntimeConfig, state: RuntimeState,
-                        interval: jax.Array,
-                        aux: Optional[_GatherAux] = None) -> jax.Array:
-    """Cell mask of one event interval in the merged view's flat order.
+def _slot_holds(cfg: RuntimeConfig, state: RuntimeState, interval: jax.Array,
+                aux: Optional[_GatherAux] = None) -> jax.Array:
+    """``[W]`` — whether each shard's slot ``interval mod K`` still HOLDS
+    ``interval`` (a recycled slot must never leak its new occupant into an
+    older interval's emission — the host guards eviction with a named
+    error, this is the in-graph belt)."""
+    k = cfg.num_intervals
+    slot_interval = (aux.slot_interval if aux is not None
+                     else state.slot_interval.reshape(-1, k))    # [W, K]
+    return slot_interval[:, jnp.mod(interval, k)] == interval
 
-    Interval ``j`` lives in slot ``j mod K``; the mask additionally
-    requires the slot to still HOLD ``j`` (a recycled slot must never
-    leak its new occupant into an older interval's emission — the host
-    guards eviction with a named error, this is the in-graph belt)."""
-    k, s = cfg.num_intervals, cfg.num_strata
+
+def _closed_view(cfg: RuntimeConfig, view: qt.SampleView,
+                 interval: jax.Array, holds: jax.Array) -> qt.SampleView:
+    """The closed interval's rows of the merged view: ``[W·K·S, N] →
+    [W·S, N]``, shard-major like the merged view.
+
+    Interval ``j`` lives in slot ``j mod K``, so its cells are one index
+    of the view's K axis; the estimators then work on the cells that
+    carry the answer's weight instead of the whole ring with the rest
+    zeroed. A shard whose slot no longer holds ``j`` contributes zero
+    counts, exactly as a masked cell would.
+    """
+    w, k, s = cfg.num_shards, cfg.num_intervals, cfg.num_strata
     slot = jnp.mod(interval, k)
-    sel = (jnp.arange(k * s, dtype=jnp.int32) // s) == slot      # [K·S]
-    if aux is not None:
-        holds = aux.slot_interval[:, slot] == interval           # [W]
-        return (holds[:, None] & sel[None, :]).reshape(-1)
-    if cfg.num_shards == 1:
-        return sel & (state.slot_interval[slot] == interval)
-    holds = state.slot_interval[:, slot] == interval             # [W]
-    return (holds[:, None] & sel[None, :]).reshape(-1)
+
+    def rows(x):
+        x = x.reshape((w, k, s) + x.shape[1:])
+        x = jax.lax.dynamic_index_in_dim(x, slot, axis=1, keepdims=False)
+        return x.reshape((w * s,) + x.shape[2:])
+
+    keep = jnp.repeat(holds, s)                                  # [W·S]
+    return qt.SampleView(values=rows(view.values),
+                         counts=jnp.where(keep, rows(view.counts), 0),
+                         taken=jnp.where(keep, rows(view.taken), 0))
 
 
 def _evaluate_interval(cfg: RuntimeConfig, registry: QueryRegistry,
                        state: RuntimeState, interval: jax.Array,
                        base_key: jax.Array, axis: Optional[str] = None):
     """Watermark-driven emission body: answer every standing query on the
-    CLOSED interval's cells (merged kinds and per-key panes restrict to
-    it; session windows read the full ring via the context).
+    CLOSED interval's cells (merged kinds and per-key panes read its
+    compacted view; session windows read the full ring via the context).
 
     ``base_key`` seeds the bootstrap paths, folded with the interval id —
     NOT with the ring's evolving lead key, whose fold count depends on
@@ -537,8 +553,12 @@ def _evaluate_interval(cfg: RuntimeConfig, registry: QueryRegistry,
     # emission points align; the merged/per-key per-interval answers
     # below are cadence-independent unconditionally.
     ctx.activity = ctx.activity & (ctx.slot_interval <= interval)[:, None]
-    iview = win.restrict_view(view, _interval_cell_mask(cfg, state,
-                                                        interval, aux))
+    iview = _closed_view(cfg, view, interval,
+                         _slot_holds(cfg, state, interval, aux))
+    if cfg.num_shards > 1:
+        # As in ``_merged_view``: both placements reduce the same
+        # materialized [W·S] rows, so their f32 sums round alike.
+        iview = jax.lax.optimization_barrier(iview)
     istats = err.stratum_stats_from_sample(
         iview.values, iview.counts, iview.taken, iview.slot_mask(),
         fixed_order=cfg.num_shards > 1)
@@ -581,15 +601,16 @@ def _pooled_stats(cfg: RuntimeConfig, stats: err.StratumStats):
 
     The controller's Neyman allocation is per *stratum* (capacity is a
     ``[S]`` knob); the emission's shared stats are per cell. Moments sum
-    across a stratum's interval cells. Sharded: ``[W·K·S] → [W, S]`` so
-    each shard's controller sees its local window.
+    across a stratum's interval cells: the whole ring's, or at a close the
+    closed interval's alone (``[W·S]``). Sharded: ``→ [W, S]`` so each
+    shard's controller sees its local window.
     """
-    k, s = cfg.num_intervals, cfg.num_strata
+    w, s = cfg.num_shards, cfg.num_strata
 
     def pool(leaf):
-        if cfg.num_shards > 1:
-            return leaf.reshape(cfg.num_shards, k, s).sum(axis=1)
-        return leaf.reshape(k, s).sum(axis=0)
+        if w > 1:
+            return leaf.reshape(w, -1, s).sum(axis=1)
+        return leaf.reshape(-1, s).sum(axis=0)
 
     return err.StratumStats(
         counts=pool(stats.counts), taken=pool(stats.taken),
@@ -792,6 +813,26 @@ class _ExecutorBase:
             for s in self._sentinels.values():
                 s.strict = telemetry.strict_retrace
         telemetry.on_run_meta(self)
+
+    def emit_cells(self) -> dict:
+        """Rows of the sample view each standing query's estimator reads
+        per emission (``kept``; one key's rows for a per-key or session
+        quantile), against the ring's ``W·K·S`` cells (``ring``).
+
+        Python ints from the shapes of an abstract trace of the emission
+        (``jax.eval_shape``): no compile, no device work, no retrace."""
+        cfg, registry = self.cfg, self.registry
+        if cfg.emission == "watermark":
+            def body(st):
+                return _evaluate_interval(cfg, registry, st, jnp.int32(0),
+                                          self._emit_base_key)[0]
+        else:
+            def body(st):
+                return _evaluate(cfg, registry, st)[0]
+        registry.rows_fed.clear()
+        jax.eval_shape(body, self.state)
+        return {"ring": int(self.state.window.intervals.counts.size),
+                "kept": dict(registry.rows_fed)}
 
     @property
     def emit_trace_count(self) -> int:

@@ -107,7 +107,7 @@ class EmissionContext:
 
     ``view``/``stats`` here are always the FULL window's shared pass:
     under watermark-driven emission the base view handed to
-    ``evaluate_view`` is restricted to the closed interval, which is
+    ``evaluate_view`` holds only the closed interval's cells, which is
     exactly what per-key tumbling panes want, while session windows keep
     reading the whole ring (a session spans intervals by definition).
     """
@@ -183,6 +183,10 @@ class QueryRegistry:
     def __init__(self, queries: Sequence[StandingQuery] = ()):
         self._queries: list[StandingQuery] = list(queries)
         self._frozen = False
+        # Rows of the sample view each query's estimator read in the
+        # latest trace of ``evaluate_view`` (per key for a per-key or
+        # session quantile): python ints from the traced shapes.
+        self.rows_fed: Dict[str, int] = {}
         names = [q.name for q in self._queries]
         if len(set(names)) != len(names):
             raise ValueError(f"duplicate query names in {names}")
@@ -245,7 +249,7 @@ class QueryRegistry:
         The executors call this directly: single-shard emissions pass the
         window's merged view; sharded emissions pass the (shard ×
         interval × stratum) concatenation (the Eq. 5 merge); watermark-
-        driven emissions pass the closed interval's restriction of it.
+        driven emissions pass the closed interval's ``[W·S]`` rows of it.
         ``ctx`` supplies the cell structure the per-key/session window
         kinds group by — merged-only registries never need it.
         """
@@ -253,6 +257,7 @@ class QueryRegistry:
         sharded = ctx is not None and ctx.num_shards > 1
         for q in self._queries:
             if q.window == "merged":
+                self.rows_fed[q.name] = view.counts.shape[0]
                 out[q.name] = self._eval_merged(q, view, stats, key,
                                                 sharded)
             else:
@@ -312,6 +317,7 @@ class QueryRegistry:
             base = view
         gid = ctx.key_of_cell(base.counts.shape[0])
         sharded = ctx.num_shards > 1
+        self.rows_fed[q.name] = base.counts.shape[0]
         gstats = err.stratum_stats_from_sample(
             base.values, base.counts, base.taken, base.slot_mask(),
             fixed_order=sharded)
@@ -328,15 +334,20 @@ class QueryRegistry:
                 gid, s)
         assert q.kind == "quantile"
         # Per-key stratified bootstrap: each key keeps its own cells and
-        # replicates (vmapped — one trace for all keys).
+        # replicates (vmapped — one trace for all keys). Cell g belongs
+        # to key g mod S, so ``[G, N] → [G/S, S, N]`` puts key k's cells
+        # on index k of the middle axis, and each key's estimator reads
+        # only its own G/S rows.
         qs = jnp.asarray(q.qs, jnp.float32)
+        by_key = jax.tree.map(
+            lambda x: x.reshape((-1, s) + x.shape[1:]), base)
 
-        def one(key_id, kk):
-            v = win.restrict_view(base, gid == key_id)
+        def one(v, kk):
             return qt.query_quantile(v, qs, method=q.method,
                                      num_replicates=q.num_replicates,
                                      key=kk)
 
         keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
             fold_in_str(key, q.name), jnp.arange(s))
-        return jax.vmap(one)(jnp.arange(s, dtype=jnp.int32), keys)
+        self.rows_fed[q.name] = base.counts.shape[0] // s
+        return jax.vmap(one, in_axes=(1, 0))(by_key, keys)
