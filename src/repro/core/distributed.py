@@ -224,6 +224,19 @@ def split_capacity(total_capacity: jax.Array, num_shards: int) -> jax.Array:
         (total_capacity + num_shards - 1) // num_shards, 1).astype(jnp.int32)
 
 
+def _aux_rows(aux_words: int, width: int) -> int:
+    """Rows of ``width`` words that hold ``aux_words`` words, padded."""
+    return -(-aux_words // width)
+
+
+def gather_words(cells: int, slots: int, aux_words: int) -> int:
+    """u32 words one device contributes to :func:`gather_cells`: its
+    ``cells`` rows of ``slots + 2`` words (samples, count, taken), then
+    the ``aux_words`` padded to whole rows of that width."""
+    width = slots + 2
+    return (cells + _aux_rows(aux_words, width)) * width
+
+
 def gather_cells(view: qt.SampleView, aux: jax.Array,
                  axis_name: str, num_shards: int) -> tuple:
     """The mesh emission merge: ONE collective per emission.
@@ -261,7 +274,7 @@ def gather_cells(view: qt.SampleView, aux: jax.Array,
          words(view.taken, jnp.int32)[:, None]], axis=-1)   # [G, N+2]
 
     a = aux.shape[0]
-    rows = -(-a // width)
+    rows = _aux_rows(a, width)
     aux_rows = jnp.concatenate(
         [aux.astype(u32), jnp.zeros((rows * width - a,), u32)]
     ).reshape(rows, width)

@@ -220,7 +220,8 @@ class Telemetry:
                       allowed_lateness=cfg.allowed_lateness,
                       num_shards=cfg.num_shards,
                       queries=describe(ex.registry),
-                      emit_cells=ex.emit_cells())
+                      emit_cells=ex.emit_cells(),
+                      gather_words=ex.gather_words())
 
     def on_emission(self, ex, em) -> None:
         """One emission was recorded (the host just blocked on results)."""

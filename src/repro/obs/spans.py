@@ -14,6 +14,9 @@ The names are fixed strings, so opening a span formats nothing:
   triggers included;
 * ``stream.dispatch`` — dispatch of the ingest step, the chunk's transfer
   (and its placement on the mesh) included;
+* ``stream.place``    — on the mesh only, the chunk's placement one shard
+  row per device (``records.place_sharded``), inside ``stream.dispatch``
+  in the pipelined executor and at the batched executor's flush;
 * ``stream.frontier`` — the host frontier mirror and the close test;
 * ``stream.emit``     — one closed interval's emission: argument
   conversion, dispatch, the wait on its results and the record;
@@ -29,8 +32,9 @@ DISPATCH = "stream.dispatch"
 FRONTIER = "stream.frontier"
 EMIT = "stream.emit"
 READBACK = "stream.readback"
+PLACE = "stream.place"
 
-NAMES = (PUSH, DISPATCH, FRONTIER, EMIT, READBACK)
+NAMES = (PUSH, DISPATCH, FRONTIER, EMIT, READBACK, PLACE)
 
 
 def span(name: str) -> jax.profiler.TraceAnnotation:

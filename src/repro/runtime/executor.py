@@ -830,9 +830,34 @@ class _ExecutorBase:
             def body(st):
                 return _evaluate(cfg, registry, st)[0]
         registry.rows_fed.clear()
-        jax.eval_shape(body, self.state)
+        # Unsharded shapes: the per-shard slicing this path traces is
+        # refused on the mesh's [W]-sharded leaves; the rows are the same.
+        jax.eval_shape(body, jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), self.state))
         return {"ring": int(self.state.window.intervals.counts.size),
                 "kept": dict(registry.rows_fed)}
+
+    def gather_words(self) -> int:
+        """u32 words one device contributes to the mesh emission's
+        all_gather (``dist.gather_cells``): its ``[K·S, N+2]`` cell rows
+        plus the aux rows; 0 off the mesh. From the shapes of an
+        abstract trace of the packing: no compile, no device work."""
+        if self._mesh is None:
+            return 0
+        cfg = self.cfg
+
+        def local(state):
+            window0 = jax.tree.map(lambda x: x[0], state.window)
+            return (win.sample_view(window0).values,
+                    _pack_aux(cfg, state, window0))
+
+        # One device's block of the [W]-sharded state, as shard_map
+        # hands it to the emission.
+        block = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((1,) + x.shape[1:], x.dtype),
+            self.state)
+        values, aux = jax.eval_shape(local, block)
+        return dist.gather_words(*values.shape, aux.shape[0])
 
     @property
     def emit_trace_count(self) -> int:
@@ -1108,8 +1133,9 @@ class BatchedExecutor(_ExecutorBase):
         stacked = _stack(self._pending)
         if self._mesh is not None:
             from repro.runtime import records
-            stacked = records.place_sharded(stacked, self._mesh,
-                                            leading_batch=True)
+            with obs_spans.span(obs_spans.PLACE):
+                stacked = records.place_sharded(stacked, self._mesh,
+                                                leading_batch=True)
         pending, n = self._pending, len(self._pending)
         self._pending = []
         lat = jnp.float32(self._last_latency)
@@ -1225,7 +1251,8 @@ class PipelinedExecutor(_ExecutorBase):
             with obs_spans.span(obs_spans.DISPATCH):
                 if self._mesh is not None:
                     from repro.runtime import records
-                    chunk = records.place_sharded(chunk, self._mesh)
+                    with obs_spans.span(obs_spans.PLACE):
+                        chunk = records.place_sharded(chunk, self._mesh)
                 self.state = self._step(self.state, chunk)  # async dispatch
             self._items_since_emit += int(chunk.values.size)
             self._chunks_since_emit += 1

@@ -132,7 +132,9 @@ def test_names_are_the_benchmark_readers():
                                             "_spans.py"))
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
-    assert bench.NAMES == spans.NAMES
+    # The mesh's placement span is read by its own reader
+    # (bench/metrics/place_ms.mesh.py), not by the shared span nesting.
+    assert bench.NAMES == tuple(n for n in spans.NAMES if n != spans.PLACE)
     assert (bench.PUSH, bench.DISPATCH, bench.FRONTIER, bench.EMIT,
             bench.READBACK) == (spans.PUSH, spans.DISPATCH, spans.FRONTIER,
                                 spans.EMIT, spans.READBACK)
