@@ -10,8 +10,9 @@ variance (Eq. 6) does not apply. The estimator stack here is:
   stream CDF; the q-quantile is ``inf{t : F̂(t) ≥ q}``. Two
   interchangeable, fully-jitted evaluation schemes:
 
-  - ``weighted_quantile`` — sorted-cumulative-weight: one ``argsort`` of
-    the slot buffer, then ``searchsorted`` on the cumulative weights.
+  - ``weighted_quantile`` — sorted-cumulative-weight: one stable sort of
+    the slot buffer that carries the weights as its payload, then
+    ``searchsorted`` on the cumulative weights.
   - ``quantile_refine`` — sort-free histogram refinement: R rounds of
     B-bin weighted histograms (the ``weighted_hist`` Pallas kernel is the
     inner loop) that shrink the bracket by B× per round, then linear
@@ -110,9 +111,9 @@ def weighted_quantile(x: jax.Array, w: jax.Array, valid: jax.Array,
     Returns the ``[Q]`` sample quantiles (exact inverse of ``F̂``).
     """
     qs = jnp.atleast_1d(jnp.asarray(qs, jnp.float32))
-    order = jnp.argsort(jnp.where(valid, x, _BIG))
-    xs = jnp.where(valid, x, _BIG)[order]
-    ws = jnp.where(valid, w, 0.0)[order]
+    xs, ws = jax.lax.sort(
+        (jnp.where(valid, x, _BIG), jnp.where(valid, w, 0.0)),
+        num_keys=1, is_stable=True)
     cw = jnp.cumsum(ws)
     total = jnp.maximum(cw[-1], 1e-20)
     idx = jnp.searchsorted(cw, qs * total, side="left")

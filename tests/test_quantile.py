@@ -5,6 +5,8 @@ bootstrap 95% CI covers the exact quantile in >= 90% of seeded trials,
 and the sharded single-psum path matches the single-shard result while
 the ingest program stays free of collectives.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -172,3 +174,107 @@ def test_query_quantile_deterministic(key):
     np.testing.assert_array_equal(np.asarray(a.value), np.asarray(b.value))
     np.testing.assert_array_equal(np.asarray(a.variance),
                                   np.asarray(b.variance))
+
+
+# ---------------------------------------------------------------------------
+# The payload sort: parity with argsort + two permutation gathers.
+# ---------------------------------------------------------------------------
+
+def _argsort_gather_quantile(x, w, valid, qs):
+    """Reference: ``argsort`` of the masked values, then ``xs[order]`` and
+    ``ws[order]`` — the form the payload sort replaces."""
+    qs = jnp.atleast_1d(jnp.asarray(qs, jnp.float32))
+    xm = jnp.where(valid, x, qt._BIG)
+    order = jnp.argsort(xm, stable=True)
+    xs = xm[order]
+    ws = jnp.where(valid, w, 0.0)[order]
+    cw = jnp.cumsum(ws)
+    total = jnp.maximum(cw[-1], 1e-20)
+    idx = jnp.searchsorted(cw, qs * total, side="left")
+    return xs[jnp.clip(idx, 0, xs.shape[0] - 1)]
+
+
+def _flat_case(seed, n, qs, live):
+    """Byte-like values with many ties, stratum-like weights; ``live``
+    says which slots are valid: all, some, none or one."""
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    x = jnp.round(jnp.exp(jax.random.normal(k1, (n,)) * 1.2 + 3.0))
+    w = jnp.array([1.0, 4.25, 37.5])[jax.random.randint(k2, (n,), 0, 3)]
+    valid = {"all": jnp.ones((n,), jnp.bool_),
+             "some": jax.random.bernoulli(k3, 0.7, (n,)),
+             "none": jnp.zeros((n,), jnp.bool_),
+             "one": jnp.arange(n) == n // 3}[live]
+    return x, w, valid, jnp.asarray(qs, jnp.float32)
+
+
+def _cells_view(seed, counts, n):
+    """A ``[G, N]`` view whose cells hold ``min(count, N)`` live slots."""
+    counts = jnp.asarray(counts, jnp.int32)
+    x = jnp.round(jnp.exp(jax.random.normal(
+        jax.random.PRNGKey(seed), (counts.shape[0], n)) * 1.4 + 6.0))
+    return qt.SampleView(values=x, counts=counts,
+                         taken=jnp.minimum(counts, n))
+
+
+@pytest.mark.parametrize("live,qs", [
+    ("all", [0.5]),
+    ("all", [0.5, 0.9, 0.99]),
+    ("some", [0.99]),
+    ("some", [0.5, 0.9, 0.99]),
+    ("none", [0.5, 0.9, 0.99]),
+    ("one", [0.95]),
+    ("one", [0.5, 0.9, 0.99]),
+], ids=["ties-q1", "ties-q3", "invalid-q1", "invalid-q3", "all_invalid-q3",
+        "one_valid-q1", "one_valid-q3"])
+def test_weighted_quantile_matches_argsort_gather(live, qs):
+    x, w, valid, q = _flat_case(7, 4099, qs, live)
+    got = jax.jit(qt.weighted_quantile)(x, w, valid, q)
+    want = jax.jit(_argsort_gather_quantile)(x, w, valid, q)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("shape", ["netflow", "taxi"])
+def test_bootstrap_matches_argsort_gather(shape, monkeypatch):
+    """32 vmapped replicates, bitwise: netflow's merged p99 over 3 cells of
+    26,215 slots, and taxi's per-key p95 vmapped over 6 keys of 1 cell."""
+    key = jax.random.PRNGKey(11)
+    if shape == "netflow":
+        view = _cells_view(3, [111_411, 17_039, 2_622], 26_215)
+
+        def run(v):
+            est = qt.query_quantile(v, [0.99], num_replicates=32, key=key)
+            return est.value, est.variance
+    else:
+        view = _cells_view(5, [4_100, 1_200, 900, 30, 1_639, 0], 1_639)
+
+        def run(v):
+            def one(vk, kk):
+                est = qt.query_quantile(vk, [0.95], num_replicates=32,
+                                        key=kk)
+                return est.value, est.variance
+            keys = jax.vmap(jax.random.fold_in, in_axes=(None, 0))(
+                key, jnp.arange(6))
+            return jax.vmap(one, in_axes=(1, 0))(
+                jax.tree.map(lambda a: a.reshape((1, 6) + a.shape[1:]), v),
+                keys)
+    got = jax.jit(run)(view)
+    monkeypatch.setattr(qt, "weighted_quantile", _argsort_gather_quantile)
+    want = jax.jit(lambda v: run(v))(view)   # a new function: traced anew
+    for g, h in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(h))
+
+
+def test_weighted_quantile_hlo_one_sort_no_slot_gather():
+    """One ``sort`` carries the weights; only ``[Q]``-sized picks gather."""
+    n = 1000
+    f32 = jax.ShapeDtypeStruct((n,), jnp.float32)
+    text = jax.jit(qt.weighted_quantile).lower(
+        f32, f32, jax.ShapeDtypeStruct((n,), jnp.bool_),
+        jax.ShapeDtypeStruct((3,), jnp.float32)).as_text()
+    assert text.count('"stablehlo.sort"') == 1
+    gathers = [l for l in text.splitlines() if '"stablehlo.gather"' in l]
+    assert gathers, "searchsorted and the final pick gather [Q] values"
+    for line in gathers:
+        out = line.rsplit("->", 1)[1]
+        dims = [int(d) for d in re.findall(r"(\d+)x", out)]
+        assert int(np.prod(dims)) != n, f"slot-sized gather: {line.strip()}"
